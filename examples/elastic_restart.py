@@ -20,6 +20,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.data import LMDataConfig, lm_batch
 from repro.distributed.sharding import use_rules
@@ -52,7 +53,8 @@ def main() -> None:
     args = ap.parse_args()
 
     n = len(jax.devices())
-    mesh = jax.make_mesh((n // 2, 2), ("data", "model"))
+    mesh = jax.make_mesh((n // 2, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     print(f"phase {args.phase}: {n} devices, mesh "
           f"{dict(zip(mesh.axis_names, mesh.devices.shape))}")
 

@@ -17,6 +17,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.distributed.pipeline import bubble_fraction, gpipe_forward  # noqa: E402
@@ -37,7 +38,8 @@ def main() -> None:
 
     xs = jax.random.normal(jax.random.fold_in(key, 1), (n_micro, mb, d))
 
-    mesh = jax.make_mesh((n_stages,), ("stage",))
+    mesh = jax.make_mesh((n_stages,), ("stage",),
+                         axis_types=(AxisType.Auto,))
     ys = gpipe_forward(stage_fn, ws, xs, mesh=mesh)
 
     # sequential reference

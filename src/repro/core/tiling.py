@@ -34,6 +34,12 @@ from typing import Iterable
 # ---------------------------------------------------------------------------
 
 V5E_VMEM_BYTES = 128 * 1024 * 1024        # 128 MiB VMEM per core
+# Scoped VMEM limit of every bounded DCL kernel (``vmem_limit_bytes`` of
+# its ``pallas_call``) and the default budget of the tile choosers below:
+# one number, so a tile the chooser accepts is one Mosaic accepts.  The
+# 32 MiB left of the physical VMEM is Mosaic's own headroom (internal
+# scratch, spilled row values of the sampler).
+VMEM_LIMIT_BYTES = 96 * 1024 * 1024
 V5E_PEAK_FLOPS_BF16 = 197e12              # 197 TFLOP/s bf16
 V5E_HBM_BW = 819e9                        # 819 GB/s
 V5E_ICI_BW = 50e9                         # ~50 GB/s per link
@@ -188,11 +194,11 @@ class TileChoice:
 
     @property
     def fits(self) -> bool:
-        return self.vmem_bytes <= V5E_VMEM_BYTES
+        return self.vmem_bytes <= VMEM_LIMIT_BYTES
 
 
 def evaluate_tile(shape: LayerShape, t: TileConfig, *, fused: bool = True,
-                  vmem_budget: int = V5E_VMEM_BYTES) -> TileChoice:
+                  vmem_budget: int = VMEM_LIMIT_BYTES) -> TileChoice:
     flops = tile_flops(shape, t)
     traffic = tile_hbm_bytes(shape, t)
     if not fused:
@@ -206,7 +212,7 @@ def evaluate_tile(shape: LayerShape, t: TileConfig, *, fused: bool = True,
 
 
 def choose_tiles(shape: LayerShape, *, fused: bool = True,
-                 vmem_budget: int = V5E_VMEM_BYTES) -> TileChoice:
+                 vmem_budget: int = VMEM_LIMIT_BYTES) -> TileChoice:
     """Pick the tiling point with the highest roofline-attainable perf
     among those whose Eq. 6/7 working set fits VMEM (paper Sec. 3.2)."""
     best: TileChoice | None = None
@@ -237,6 +243,14 @@ def band_extent(tile: int, *, kernel_size: int, stride: int,
     return (tile - 1) * stride + (kernel_size - 1) * dilation + 2 * hb + 2
 
 
+def staged_width(band_w: int) -> int:
+    """Columns a forward kernel DMAs per Eq. 6 band: ``band_w`` rounded
+    up to whole sublane tiles — Mosaic refuses int8 band copies of a
+    ragged width.  The extra columns are zero padding the sampler never
+    weights."""
+    return -(-band_w // MXU_SUBLANE) * MXU_SUBLANE
+
+
 def out_hw(h: int, w: int, *, kernel_size: int, stride: int,
            dilation: int = 1) -> tuple[int, int]:
     """'Same'-padded output spatial dims of one DCL invocation."""
@@ -253,8 +267,8 @@ def dcl_dataflow_hbm_bytes(shape: LayerShape, t: TileConfig, *,
     """Input-dataflow HBM bytes for one whole DCL layer.
 
     ``zero_copy``: the padded input stays in HBM; the kernel DMAs one
-    (band_h, band_w) window per (row-tile, width-tile, M-tile, C-chunk)
-    grid step — halo rows are re-read at tile boundaries, nothing is
+    (band_h, staged_width(band_w)) window per (row-tile, width-tile,
+    M-tile, C-chunk) grid step — halo rows are re-read at tile boundaries, nothing is
     duplicated.
 
     ``materialized_band``: the legacy XLA path reads the padded input
@@ -277,8 +291,8 @@ def dcl_dataflow_hbm_bytes(shape: LayerShape, t: TileConfig, *,
     w_full = wo * s + band_extent(1, kernel_size=k, stride=s,
                                   dilation=dilation, offset_bound=b) - s
     if dataflow == "zero_copy":
-        band_w = band_extent(t.t_w, kernel_size=k, stride=s,
-                             dilation=dilation, offset_bound=b)
+        band_w = staged_width(band_extent(t.t_w, kernel_size=k, stride=s,
+                                          dilation=dilation, offset_bound=b))
         reads = h_tiles * w_tiles * m_passes * band_h * band_w * c
         return batch * reads * bytes_per_elem
     if dataflow == "materialized_band":
@@ -618,32 +632,54 @@ def dcl_train_hbm_bytes(shape: LayerShape, t: TileConfig, *,
 
 
 def zerocopy_vmem_bytes(shape: LayerShape, t: TileConfig, *,
-                        dilation: int = 1, bytes_per_elem: int = 2,
-                        aux_bytes_per_elem: int | None = None) -> int:
-    """VMEM working set of the zero-copy fused kernel: double-buffered
-    Eq. 6 (band_h, band_w) input scratch + weight block + offsets block
-    + fp32/int32 accumulator + output tile.
+                        dilation: int = 1, bytes_per_elem: int = 4,
+                        aux_bytes_per_elem: int | None = None,
+                        fuse_offsets: bool = False) -> int:
+    """VMEM working set of the zero-copy fused forward kernel — the
+    buffers ``kernels.band_pipeline.forward_call`` allocates:
 
-    ``aux_bytes_per_elem`` sizes the offsets block and the output tile
+    * the staged Eq. 6 band (``band_h`` x ``staged_width(band_w)``),
+      double-buffered at the datapath width (one slot for fused-offset
+      plans, which stage it once per spatial tile);
+    * its lane-chunked fp32 copy, which the sampler reads;
+    * the sampled patch tile (``tile_h*tile_w`` x ``K^2*tile_c``, int8
+      on the 1-byte datapath, else fp32), the spill slots Mosaic gives
+      it when the MXU contraction reads it as one value, and the 4-byte
+      accumulator;
+    * the double-buffered pipeline blocks: weights, offsets (or, fused,
+      the int8 offset-conv weights plus an fp32 offset scratch) and the
+      output tile.
+
+    ``aux_bytes_per_elem`` sizes the offsets and the output tile
     separately from the datapath — the int8 kernel keeps both fp32
-    (addresses are full precision; the dequant epilogue emits fp32), so
-    the dtype-aware chooser passes 4 there while the band and weight
-    blocks shrink to 1 byte/elem.
+    (addresses are full precision; the dequant epilogue emits fp32).
     """
     k2 = shape.kernel_size ** 2
     aux_b = aux_bytes_per_elem or bytes_per_elem
     band_h = band_extent(t.t_h, kernel_size=shape.kernel_size,
                          stride=shape.stride, dilation=dilation,
                          offset_bound=shape.offset_bound)
-    band_w = band_extent(t.t_w, kernel_size=shape.kernel_size,
-                         stride=shape.stride, dilation=dilation,
-                         offset_bound=shape.offset_bound)
-    band = 2 * band_h * band_w * t.t_n * bytes_per_elem   # double buffer
-    wgt = k2 * t.t_n * t.t_m * bytes_per_elem
-    offs = t.t_h * t.t_w * 2 * k2 * aux_b
-    acc = t.t_h * t.t_w * t.t_m * 4
-    out = t.t_h * t.t_w * t.t_m * aux_b
-    return band + wgt + offs + acc + out
+    band_w = staged_width(band_extent(t.t_w, kernel_size=shape.kernel_size,
+                                      stride=shape.stride, dilation=dilation,
+                                      offset_bound=shape.offset_bound))
+    band_elems = band_h * band_w * t.t_n
+    n_buf = 1 if fuse_offsets else 2
+    band = n_buf * band_elems * bytes_per_elem + band_elems * 4
+    pixels = t.t_h * t.t_w
+    patches = pixels * k2 * t.t_n * (1 if bytes_per_elem == 1 else 4)
+    # Spill slots of the contraction's patch operand, from compiles for
+    # v5e: 4.2x the fp32 tile (78.4 MiB for an 18.0 MiB tile, 150.8 MiB
+    # for 36.0 MiB — the HIGHEST-precision operand is split into bf16
+    # parts), budgeted at 4.5x; the int8 operand is read once.
+    spill = patches if bytes_per_elem == 1 else patches * 9 // 2
+    acc = pixels * t.t_m * 4
+    wgt = 2 * k2 * t.t_n * t.t_m * bytes_per_elem
+    if fuse_offsets:
+        offs = 2 * k2 * t.t_n * 2 * k2 + pixels * 2 * k2 * 4
+    else:
+        offs = 2 * pixels * 2 * k2 * aux_b
+    out = 2 * pixels * t.t_m * aux_b
+    return band + patches + spill + acc + wgt + offs + out
 
 
 def zerocopy_bwd_vmem_bytes(shape: LayerShape, t: TileConfig, *,
@@ -680,6 +716,15 @@ def _divisor_at_most(n: int, cap: int) -> int:
     return 1
 
 
+def _channel_tiles(n: int, caps: tuple[int, ...]) -> list[int]:
+    """Channel-tile candidates: divisors of ``n`` near ``caps``, kept
+    only where Mosaic can window them — the whole extent or whole
+    128-lane multiples."""
+    return sorted({d for d in (_divisor_at_most(n, cap) for cap in
+                               (*caps, n))
+                   if d == n or d % MXU_LANE == 0})
+
+
 @dataclasses.dataclass(frozen=True)
 class KernelTiles:
     """Concrete (divisor-snapped) tile sizes for the Pallas kernels."""
@@ -695,7 +740,7 @@ def choose_kernel_tiles(shape: LayerShape, *, batch: int = 1,
                         objective: str = "training",
                         dtype: str | None = None,
                         cores: int = 1,
-                        vmem_budget: int = V5E_VMEM_BYTES) -> KernelTiles:
+                        vmem_budget: int = VMEM_LIMIT_BYTES) -> KernelTiles:
     """Pick (tile_h, tile_w, tile_c, tile_m) for the zero-copy fused
     kernels: minimize modeled whole-layer HBM traffic among tile points
     whose double-buffered working set fits VMEM, then snap the channel
@@ -711,9 +756,8 @@ def choose_kernel_tiles(shape: LayerShape, *, batch: int = 1,
     ``dtype`` makes both budgets element-width-aware: ``"int8"`` sizes
     the Eq. 6 band and weight blocks at 1 byte/elem (4x the band per
     VMEM byte vs fp32 — the quantized-datapath win the paper's
-    fixed-point design banks on), ``"bf16"``/``"fp32"`` at 2/4.  The
-    legacy ``dtype=None`` keeps the PR-1/2 convention (bf16 VMEM
-    working set, fp32 traffic) so existing chooser results are stable.
+    fixed-point design banks on), ``"bf16"``/``"fp32"`` at 2/4.
+    ``dtype=None`` is the fp32 kernel (4-byte working set and traffic).
 
     ``cores`` evaluates the training objective with the Megacore
     backward split's traffic (extra partial-d_weights flushes + reduce
@@ -733,7 +777,7 @@ def choose_kernel_tiles(shape: LayerShape, *, batch: int = 1,
         raise ValueError(f"unknown objective {objective!r}")
     if cores < 1:
         raise ValueError(f"cores={cores} must be >= 1")
-    vmem_b = dtype_bytes(dtype) if dtype is not None else 2
+    vmem_b = dtype_bytes(dtype) if dtype is not None else 4
     traffic_b = dtype_bytes(dtype) if dtype is not None else 4
     # The int8 kernel keeps offsets/output fp32 (address precision +
     # dequant epilogue) — size those VMEM terms at 4 bytes, not 1.
@@ -742,10 +786,8 @@ def choose_kernel_tiles(shape: LayerShape, *, batch: int = 1,
                     stride=shape.stride, dilation=dilation)
     ths = sorted({min(t, max(1, ho)) for t in (1, 2, 4, 8, 16, 32)})
     tws = sorted({min(t, max(1, wo)) for t in (8, 16, 32, 64, 128)})
-    tns = sorted({_divisor_at_most(shape.c_in, cap)
-                  for cap in (32, 64, 128, 256, 512, shape.c_in)})
-    tms = sorted({_divisor_at_most(shape.c_out, cap)
-                  for cap in (32, 64, 128, 256, shape.c_out)})
+    tns = _channel_tiles(shape.c_in, (32, 64, 128, 256, 512))
+    tms = _channel_tiles(shape.c_out, (32, 64, 128, 256))
     traffic_fn = (functools.partial(dcl_train_hbm_bytes, cores=cores)
                   if objective == "training" else dcl_total_hbm_bytes)
     best: tuple[tuple, TileConfig] | None = None
@@ -785,7 +827,7 @@ def neighbor_kernel_tiles(shape: LayerShape, seed: KernelTiles, *,
                           dilation: int = 1,
                           objective: str = "training",
                           dtype: str | None = None,
-                          vmem_budget: int = V5E_VMEM_BYTES,
+                          vmem_budget: int = VMEM_LIMIT_BYTES,
                           radius: int = 1) -> list[KernelTiles]:
     """VMEM-feasible tile candidates around ``seed`` — the search space
     of the measured-time autotuner (``repro.tune``).
@@ -801,16 +843,14 @@ def neighbor_kernel_tiles(shape: LayerShape, seed: KernelTiles, *,
     """
     if objective not in ("forward", "training"):
         raise ValueError(f"unknown objective {objective!r}")
-    vmem_b = dtype_bytes(dtype) if dtype is not None else 2
+    vmem_b = dtype_bytes(dtype) if dtype is not None else 4
     aux_b = 4 if dtype == "int8" else None
     ho, wo = out_hw(shape.h, shape.w, kernel_size=shape.kernel_size,
                     stride=shape.stride, dilation=dilation)
     ths = sorted({min(t, max(1, ho)) for t in (1, 2, 4, 8, 16, 32)})
     tws = sorted({min(t, max(1, wo)) for t in (8, 16, 32, 64, 128)})
-    tns = sorted({_divisor_at_most(shape.c_in, cap)
-                  for cap in (32, 64, 128, 256, 512, shape.c_in)})
-    tms = sorted({_divisor_at_most(shape.c_out, cap)
-                  for cap in (32, 64, 128, 256, shape.c_out)})
+    tns = _channel_tiles(shape.c_in, (32, 64, 128, 256, 512))
+    tms = _channel_tiles(shape.c_out, (32, 64, 128, 256))
 
     def near(ladder: list[int], v: int) -> list[int]:
         i = min(range(len(ladder)), key=lambda j: abs(ladder[j] - v))
@@ -843,7 +883,7 @@ def neighbor_kernel_tiles(shape: LayerShape, seed: KernelTiles, *,
 
 
 def max_offset_bound_fitting(kernel_size: int, stride: int, t_w: int,
-                             t_n: int, vmem_budget: int = V5E_VMEM_BYTES,
+                             t_n: int, vmem_budget: int = VMEM_LIMIT_BYTES,
                              *, bytes_per_elem: int = 2) -> float:
     """Inverse of Eq. 6: largest offset bound B whose input tile still
     fits the budget.  This is what couples the Eq. 5 regularizer strength

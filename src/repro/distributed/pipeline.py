@@ -18,7 +18,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Array = jax.Array
@@ -68,11 +67,11 @@ def gpipe_forward(stage_fn: Callable[[Any, Array], Array],
         _, ys = jax.lax.fori_loop(0, ticks, tick, (carry0, ys0))
         return ys[None]          # (1, n_micro, ...) per stage
 
-    stacked = shard_map(
+    stacked = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis_name), P()),
         out_specs=P(axis_name),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, xs)
     return stacked[-1]           # last stage's outputs
 

@@ -47,10 +47,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.tiling import spatial_halo_rows
+from repro.core.tiling import spatial_halo_rows, staged_width
 from repro.kernels import plan as _plan
 from repro.kernels.band_pipeline import band_geometry
 from repro.kernels.deform_conv_bwd import deform_conv_bwd_zerocopy
@@ -233,6 +232,7 @@ def _shard_slab(x_ext: Array, *, kernel_size: int, stride: int,
     _, band_w = band_geometry(kernel_size=kernel_size, stride=stride,
                               dilation=dilation, offset_bound=offset_bound,
                               tile_h=tile_w)
+    band_w = staged_width(band_w)
     p0 = pad + hb
     halo = p0 + 1
     h_tiles = ho // tile_h
@@ -352,10 +352,10 @@ def deform_conv_spatial(spec: _plan.DCSpec, sspec: SpatialSpec, x: Array,
     call; differentiable via the fused backward kernel + halo-gradient
     return (see module docstring)."""
     ps = sspec.pspec(4)
-    fn = shard_map(functools.partial(_spatial_forward, spec, sspec),
-                   mesh=sspec.mesh,
-                   in_specs=(ps, ps, P(None, None, None)),
-                   out_specs=ps, check_rep=False)
+    fn = jax.shard_map(functools.partial(_spatial_forward, spec, sspec),
+                       mesh=sspec.mesh,
+                       in_specs=(ps, ps, P(None, None, None)),
+                       out_specs=ps, check_vma=False)
     return fn(x, offsets, w)
 
 
@@ -367,10 +367,10 @@ def _deform_conv_spatial_bwd(spec, sspec, res, gy):
     x, offsets, w = res
     ps = sspec.pspec(4)
     rep_w = P(None, None, None)
-    fn = shard_map(functools.partial(_spatial_backward, spec, sspec),
-                   mesh=sspec.mesh,
-                   in_specs=(ps, ps, rep_w, ps),
-                   out_specs=(ps, ps, rep_w), check_rep=False)
+    fn = jax.shard_map(functools.partial(_spatial_backward, spec, sspec),
+                       mesh=sspec.mesh,
+                       in_specs=(ps, ps, rep_w, ps),
+                       out_specs=(ps, ps, rep_w), check_vma=False)
     return fn(x, offsets, w, gy)
 
 
@@ -437,7 +437,7 @@ def spatial_int8_forward(x: Array, offsets: Array, w: Array, *,
         return y[:, :ho, :wo]
 
     ps = sspec.pspec(4)
-    fn = shard_map(body, mesh=sspec.mesh,
-                   in_specs=(ps, ps, P(None, None, None), P(None, None)),
-                   out_specs=ps, check_rep=False)
+    fn = jax.shard_map(body, mesh=sspec.mesh,
+                       in_specs=(ps, ps, P(None, None, None), P(None, None)),
+                       out_specs=ps, check_vma=False)
     return fn(xq, offsets, wq, scale).astype(x.dtype)

@@ -22,7 +22,7 @@ double-buffered ``make_async_copy`` band stager; see
 The zero-copy dataflow is the default: the padded input stays whole in
 ANY/HBM and each (row-tile, width-tile) Eq. 6 band is DMA'd into
 double-buffered VMEM scratch by the kernel itself, overlapping the next
-band's fetch with the current tile's gather + MXU work.  The legacy
+band's fetch with the current tile's sampling + MXU work.  The legacy
 HBM-materialized banded dataflow is kept behind ``dataflow="banded"``
 as the parity baseline.
 

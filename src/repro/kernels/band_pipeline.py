@@ -4,7 +4,7 @@ Every bounded DCL kernel in this package runs the same dataflow: the
 padded input stays whole in ``ANY``/HBM, and per (batch, row-tile,
 width-tile[, M-tile], C-chunk) grid step one Eq. 6 ``(band_h, band_w)``
 band chunk streams into double-buffered VMEM scratch via
-``pltpu.make_async_copy`` while the previous chunk's gather + MXU work
+``pltpu.make_async_copy`` while the previous chunk's sampling + MXU work
 rides on top.  Before this module, four kernels (``deform_sample``,
 ``deform_conv_fused``, ``deform_conv_q``, ``deform_conv_bwd``) each
 re-implemented that staging, the grid construction, and the accumulator
@@ -35,9 +35,10 @@ New capabilities the emitter unlocks (ROADMAP int8 follow-ups):
   a strict subset of the Eq. 6 band (the band covers the deformed taps,
   the offset conv needs only the undeformed ones).  With the whole
   channel extent staged (``c_steps == 1`` — enforced) the kernel
-  computes the offsets from the already-staged int8 band with one
-  static-index im2col gather + int8 MXU contraction and a fp32 dequant:
-  no separate fp32 offset pass, and the offsets never exist in HBM.
+  computes the offsets from the already-staged int8 band with nine
+  static window loads per row + an int8 MXU contraction and a fp32
+  dequant: no separate fp32 offset pass, and the offsets never exist
+  in HBM.
 * **int8 output emission with per-channel requant**
   (``epilogue="requant"``): the int32 accumulator is rescaled by
   ``s_x * s_w[m] / s_y`` (bias folded as ``b[m] / s_y``), rounded and
@@ -45,9 +46,9 @@ New capabilities the emitter unlocks (ROADMAP int8 follow-ups):
   back-to-back DCLs chain int8 -> int8 with no fp32 HBM round-trip
   between layers (``ops.deform_conv_chain``).
 
-Geometry helpers (``band_geometry``, ``corner_geometry``, the bilinear
-gathers) live here too so the emitter is self-contained; the kernel
-modules re-export them for compatibility.
+Geometry helpers (``band_geometry``, ``corner_geometry``) and the
+shifted-window sampler (``sample_row_taps``) live here too, so the
+emitter is self-contained.
 """
 from __future__ import annotations
 
@@ -60,11 +61,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import tpu_compiler_params
-
 Array = jax.Array
 
 N_BUFFERS = 2     # double buffering: fetch band i+1 while computing band i
+LANES = 128       # vreg lane width: the sampler's channel chunk
 
 
 # ---------------------------------------------------------------------------
@@ -93,7 +93,7 @@ def _tap_grid(*, kernel_size: int, stride: int, dilation: int, halo: int,
     band starting ``halo`` rows/cols before the first tap.  The single
     source of the Eq. 6 base algebra — shared by the bilinear corner
     geometry (which adds the offsets on top) and the fused offset-conv
-    stage (which gathers exactly these positions)."""
+    stage (which reads exactly these positions)."""
     k, s, d = kernel_size, stride, dilation
     k2 = k * k
     ky = jax.lax.broadcasted_iota(jnp.int32, (k, k), 0).reshape(k2) * d
@@ -112,10 +112,10 @@ def corner_geometry(off, *, kernel_size: int, stride: int, dilation: int,
     off: (tile_h, wo, K*K, 2) raw offsets (clamped here to the Eq. 5 bound).
     Returns (y0, x0, ty, tx): int32 top-left corner indices and fp32
     fractional coefficients, each (tile_h, wo, K*K).  Shared between the
-    forward gather (``_bilinear_from_band``) and the backward kernel of
-    ``deform_conv_bwd.py`` — the same bound ``B`` that keeps forward
-    gathers in-band keeps backward scatters in-band, so both sides use
-    one geometry.
+    backward kernel of ``deform_conv_bwd.py`` and, row by row through
+    the same ``floor``/fraction arithmetic (``_floor_frac``), by the
+    forward sampler — the same bound ``B`` that keeps forward samples
+    in-band keeps backward scatters in-band.
     """
     k, s, d = kernel_size, stride, dilation
     hb = int(math.ceil(offset_bound))       # static: offset_bound is Python
@@ -139,68 +139,114 @@ def corner_geometry(off, *, kernel_size: int, stride: int, dilation: int,
     return y0f.astype(jnp.int32), x0f.astype(jnp.int32), ty, tx
 
 
-def _bilinear_from_band(band, off, *, kernel_size: int, stride: int,
-                        dilation: int, offset_bound: float, tile_h: int,
-                        wo: int):
-    """Sample (tile_h, wo, K*K) positions from a VMEM band.
+def _floor_frac(base, off):
+    """Bilinear corner of ``base + off``: the relative floor
+    ``floor(base + off) - base`` (an exact small integer, in fp32) and
+    the fractional coefficient.  ``base`` is an integer band-local tap
+    position; the sum is formed in fp32 exactly as ``corner_geometry``
+    forms it, so both sides see the same coefficients."""
+    base_f = jnp.asarray(base).astype(jnp.float32)
+    pos = base_f + off
+    p0 = jnp.floor(pos)
+    return p0 - base_f, pos - p0
 
-    band: (band_h, w_pad, tc) zero-padded input rows
-    off:  (tile_h, wo, K*K, 2) raw offsets (clamped here)
-    returns (tile_h, wo, K*K, tc) interpolated values
+
+def _tent(rel, frac, shift):
+    """Bilinear weight of one shift of the bounded window along one
+    axis: ``1 - frac`` at the floor corner, ``frac`` at the next one, 0
+    at every other shift."""
+    return jnp.where(rel == shift, 1 - frac,
+                     jnp.where(rel == shift - 1, frac, jnp.zeros_like(frac)))
+
+
+def sample_row_taps(chunks, off, t, spec: "BandSpec"):
+    """Bilinearly sample output row ``t`` of one tile from a staged band.
+
+    chunks: refs of shape (band_h, band_w, lanes) — the staged band split
+            into lane chunks (their concatenation along lanes is the C
+            chunk); every load is a static shifted (and, for stride 2,
+            strided) window, which Mosaic lowers without a gather
+    off:    ref (tile_h, tile_w, 2*K*K) of raw offsets (clamped here to
+            the Eq. 5 bound)
+    t:      output row within the tile (traced scalar)
+    returns K*K fp32 arrays of shape (tile_w, C-chunk), tap-major.
+
+    Because the trained bound B is static, every sample of tap
+    (ky, kx) lies in the (2*ceil(B)+2)^2 window of shifts around its
+    undeformed position, so the sample is the tent-weighted sum of those
+    shifted windows.  Only the bilinear corners carry non-zero weight,
+    and they are met in the order (y0, x0), (y0, x0+1), (y0+1, x0),
+    (y0+1, x0+1): values accumulate in fp32 with fp32 coefficients,
+    corner by corner, as a gather of the four corners would.
     """
-    k2 = kernel_size * kernel_size
-    band_h, w_pad, tc = band.shape
-    y0, x0, ty, tx = corner_geometry(
-        off, kernel_size=kernel_size, stride=stride, dilation=dilation,
-        offset_bound=offset_bound, tile_h=tile_h, wo=wo)
+    k, s, d, hb = spec.kernel_size, spec.stride, spec.dilation, spec.halo
+    bound = spec.offset_bound
+    tw = spec.tile_w
+    col_shifts = range(-hb, hb + 2)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (tw, 1), 0) * s
+    taps = []
+    for ky in range(k):
+        row0 = t * s + hb + ky * d
+        for kx in range(k):
+            tap = ky * k + kx
+            oy = jnp.clip(off[t, :, pl.ds(2 * tap, 1)].astype(jnp.float32),
+                          -bound, bound)
+            ox = jnp.clip(off[t, :, pl.ds(2 * tap + 1, 1)].astype(
+                jnp.float32), -bound, bound)
+            rel_y, frac_y = _floor_frac(row0, oy)
+            wx = [_tent(*_floor_frac(cols + (hb + kx * d), ox), dx)
+                  for dx in col_shifts]
 
-    flat = band.reshape(band_h * w_pad, tc)
-    p = tile_h * wo * k2
+            # Row shifts loop (rows are a dynamic leading index); column
+            # shifts stay static (sublane offsets must be).  Shifts are
+            # visited row-major, so the corners accumulate in gather
+            # order and the zero-weight shifts add exact zeros.
+            def _row_shift(i, accs, row0=row0, kx=kx, rel_y=rel_y,
+                           frac_y=frac_y, wx=wx):
+                dy = i - hb
+                wy = _tent(rel_y, frac_y, dy.astype(jnp.float32))
+                accs = list(accs)
+                for ix, dx in enumerate(col_shifts):
+                    wgt = wy * wx[ix]
+                    for c, chunk in enumerate(chunks):
+                        v = chunk[row0 + dy,
+                                  pl.ds(hb + kx * d + dx, tw, stride=s), :]
+                        accs[c] = accs[c] + v.astype(jnp.float32) * wgt
+                return tuple(accs)
+            zeros = tuple(jnp.zeros((tw, ch.shape[-1]), jnp.float32)
+                          for ch in chunks)
+            parts = jax.lax.fori_loop(0, len(col_shifts), _row_shift, zeros)
+            taps.append(parts[0] if len(parts) == 1
+                        else jnp.concatenate(parts, axis=-1))
+    return taps
 
-    def corner(yc, xc, wgt):
-        idx = (yc * w_pad + xc).reshape(p)
-        v = jnp.take(flat, idx, axis=0)           # VMEM gather — in-band
-        return v.astype(jnp.float32) * wgt.reshape(p, 1)
 
-    # Values accumulate in fp32, round once.
-    out = corner(y0, x0, (1 - ty) * (1 - tx))
-    out += corner(y0, x0 + 1, (1 - ty) * tx)
-    out += corner(y0 + 1, x0, ty * (1 - tx))
-    out += corner(y0 + 1, x0 + 1, ty * tx)
-    return out.reshape(tile_h, wo, k2, tc).astype(band.dtype)
+def for_each_row(spec: "BandSpec", body) -> None:
+    """Run ``body(t)`` for every output row of the tile — a loop, not an
+    unrolled tile, so the emitted kernel's size does not grow with
+    ``tile_h``."""
+    def _row(t, carry):
+        body(t)
+        return carry
+    jax.lax.fori_loop(0, spec.tile_h, _row, 0)
 
 
-def _bilinear_int8_from_band(band, off, *, kernel_size: int, stride: int,
-                             dilation: int, offset_bound: float,
-                             tile_h: int, wo: int):
-    """Sample an int8 VMEM band with fp32 coefficients -> int8 patches.
+def unpack_band(band, samp_ref) -> None:
+    """Copy a staged band (band_h, band_w, C-chunk) of any dtype into
+    the fp32 sampling scratch (n_chunks, band_h, band_w, lanes).
 
-    band: (band_h, w_pad, tc) int8; off: (tile_h, wo, K*K, 2) raw.
-    Returns (tile_h*wo*K*K, tc) int8 — integer values on the activation
-    grid (the convex bilinear mix of int8 values stays in [-127, 127]).
-    """
-    k2 = kernel_size * kernel_size
-    band_h, w_pad, tc = band.shape
-    y0, x0, ty, tx = corner_geometry(
-        off, kernel_size=kernel_size, stride=stride, dilation=dilation,
-        offset_bound=offset_bound, tile_h=tile_h, wo=wo)
+    Mosaic's shifted and strided window loads need 32-bit elements and,
+    when strided, a 128-lane minor dimension, so the sampler reads this
+    lane-chunked fp32 copy; the DMA itself stays at the band dtype (int8
+    bands keep their 4x HBM saving).  int8 values are exact in fp32."""
+    n_chunks, band_h, _, lanes = samp_ref.shape
 
-    flat = band.reshape(band_h * w_pad, tc)
-    p = tile_h * wo * k2
-    idx00 = (y0 * w_pad + x0).reshape(p)
-    ty = ty.reshape(p, 1)
-    tx = tx.reshape(p, 1)
-
-    def gat(idx):
-        return jnp.take(flat, idx, axis=0).astype(jnp.float32)
-
-    # Same corner order + accumulation order as the fp32 gather, so the
-    # pre-round fp32 values match ``_bilinear_from_band`` bit-for-bit.
-    out = gat(idx00) * ((1 - ty) * (1 - tx))
-    out += gat(idx00 + 1) * ((1 - ty) * tx)
-    out += gat(idx00 + w_pad) * (ty * (1 - tx))
-    out += gat(idx00 + w_pad + 1) * (ty * tx)
-    return jnp.round(out).astype(jnp.int8)
+    def _row(r, carry):
+        for c in range(n_chunks):
+            samp_ref[c, r] = band[r, :, pl.ds(c * lanes, lanes)].astype(
+                jnp.float32)
+        return carry
+    jax.lax.fori_loop(0, band_h, _row, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -224,13 +270,14 @@ def make_band_dma(x_hbm, band_ref, sem_ref, *, batch, row0, col0, c0,
 class BandStager:
     """Double-buffered Eq. 6 band staging for one (batch, row-tile,
     width-tile) grid position: chunk ``cc+1``'s HBM -> VMEM copy rides
-    under chunk ``cc``'s gather + MXU work.
+    under chunk ``cc``'s sampling + MXU work.
 
     ``stage(cc, c_steps)`` is the whole pipeline (warmup at the first
-    chunk, prefetch of the next, wait on the current) and returns the
-    staged band view; kernels that need to interleave other DMAs (the
-    backward's d_input read-modify-write) call ``warmup`` / ``prefetch``
-    / ``wait`` individually to keep their overlap structure explicit.
+    chunk, prefetch of the next, wait on the current) and returns a ref
+    view of the staged band; kernels that need to interleave other DMAs
+    (the backward's d_input read-modify-write) call ``warmup`` /
+    ``prefetch`` / ``wait`` individually to keep their overlap structure
+    explicit.
     """
 
     def __init__(self, x_hbm, band_ref, sem_ref, *, batch, row0, col0,
@@ -272,7 +319,10 @@ class BandStager:
         def _warmup():
             self.warmup()
         self.prefetch(cc, c_steps)
-        return self.wait(cc)
+        self.dma(cc, cc % N_BUFFERS).wait()
+        return self.band_ref.at[cc % N_BUFFERS]
+
+
 
 
 # ---------------------------------------------------------------------------
@@ -311,12 +361,20 @@ class BandSpec:
                              offset_bound=self.offset_bound,
                              tile_h=self.tile_w)[1]
 
+    @property
+    def stage_w(self) -> int:
+        """Columns the forward kernels DMA per band: ``band_w`` rounded
+        up to whole sublane tiles (Mosaic refuses int8 band copies of a
+        ragged width)."""
+        from repro.core.tiling import staged_width
+        return staged_width(self.band_w)
+
     def check_padded(self, hp: int, wp: int, h_tiles: int,
                      w_tiles: int) -> None:
         s = self.stride
         assert (h_tiles - 1) * self.tile_h * s + self.band_h <= hp, \
             "underpadded H"
-        assert (w_tiles - 1) * self.tile_w * s + self.band_w <= wp, \
+        assert (w_tiles - 1) * self.tile_w * s + self.stage_w <= wp, \
             "underpadded W"
 
 
@@ -359,11 +417,22 @@ class DCLPlan:
     def contract(self) -> bool:
         return self.tile_m is not None
 
+    @property
+    def lanes(self) -> int:
+        """Lane width of one sampling chunk: 128 where the channel tile
+        splits into whole vreg lanes, else the whole (small) tile."""
+        return LANES if self.tile_c % LANES == 0 else self.tile_c
+
     def jnp_band_dtype(self):
         return jnp.dtype(self.band_dtype)
 
     def jnp_acc_dtype(self):
         return jnp.int32 if self.acc_dtype == "int32" else jnp.float32
+
+    def jnp_patch_dtype(self):
+        """The MXU operand type of the sampled patches: int8 values
+        re-rounded onto the activation grid, or fp32."""
+        return jnp.int8 if self.band_dtype == "int8" else jnp.float32
 
     # -- shared scratch/grid builders ---------------------------------
     def band_scratch(self):
@@ -372,8 +441,14 @@ class DCLPlan:
         # nothing to overlap, so a single slot halves the kernel's
         # largest VMEM buffer.
         n_buf = 1 if self.fuse_offsets else N_BUFFERS
-        return pltpu.VMEM((n_buf, self.band.band_h, self.band.band_w,
+        return pltpu.VMEM((n_buf, self.band.band_h, self.band.stage_w,
                            self.tile_c), self.jnp_band_dtype())
+
+    def sample_scratch(self):
+        """The lane-chunked fp32 copy of the staged band that the
+        sampler reads (``unpack_band``)."""
+        return pltpu.VMEM((self.tile_c // self.lanes, self.band.band_h,
+                           self.band.stage_w, self.lanes), jnp.float32)
 
     def dma_sem(self):
         return pltpu.SemaphoreType.DMA((N_BUFFERS,))
@@ -381,50 +456,40 @@ class DCLPlan:
     def stager(self, x_hbm, band_ref, sem_ref, *, batch, row0, col0):
         return BandStager(x_hbm, band_ref, sem_ref, batch=batch, row0=row0,
                           col0=col0, band_h=self.band.band_h,
-                          band_w=self.band.band_w, tile_c=self.tile_c)
-
-    def sample(self, band, off_raw):
-        """Dtype-dispatched bilinear gather of one tile from the staged
-        band: fp32 values, or int8 re-rounded onto the activation grid
-        (the quantized datapath's patch requantization)."""
-        b = self.band
-        fn = _bilinear_int8_from_band if self.band_dtype == "int8" \
-            else _bilinear_from_band
-        return fn(band, off_raw, kernel_size=b.kernel_size, stride=b.stride,
-                  dilation=b.dilation, offset_bound=b.offset_bound,
-                  tile_h=b.tile_h, wo=b.tile_w)
+                          band_w=band_ref.shape[-2], tile_c=self.tile_c)
 
 
-def offset_conv_stage(plan: DCLPlan, band, woff_ref, off_scale_ref,
-                      off_bias_ref):
-    """Fused offset-conv stage: offsets from the already-staged band.
+def offset_conv_row(plan: DCLPlan, chunks, t, woff_ref, off_scale_ref,
+                    off_bias_ref):
+    """Fused offset-conv stage for output row ``t``: offsets from the
+    already-staged band.
 
-    The offset conv's taps are the *undeformed* grid positions — a
-    static-index subset of the Eq. 6 band (band-local row ``t*s + hb +
-    ky*d`` for output row ``t``) — so one im2col gather + int8 MXU
-    contraction produces the raw offsets without any extra HBM traffic:
+    The offset conv's taps are the *undeformed* grid positions — static
+    windows of the Eq. 6 band (band-local row ``t*s + hb + ky*d``,
+    columns ``hb + kx*d + u*s``) — so one int8 MXU contraction per row
+    produces the raw offsets without any extra HBM traffic:
 
         off[t, u, :] = (sum_{ky,kx,c} q_x[tap] * q_woff) * s_x*s_woff + b
 
     (exact int32 accumulation, fp32 dequant).  Requires the whole
     channel extent staged (``c_steps == 1`` — the offsets must be
     complete before the first bilinear sample consumes them).
-    Returns raw fp32 offsets (tile_h, tile_w, K*K, 2); the Eq. 5 clamp
-    happens in ``corner_geometry`` exactly as for streamed offsets.
+    Returns raw fp32 offsets (tile_w, 2*K*K); the Eq. 5 clamp happens in
+    the sampler exactly as for streamed offsets.
     """
     b = plan.band
-    k2 = b.k2
-    band_h, band_w, tc = band.shape
-    rows, cols = _tap_grid(kernel_size=b.kernel_size, stride=b.stride,
-                           dilation=b.dilation, halo=b.halo,
-                           tile_h=b.tile_h, tile_w=b.tile_w)
-    idx = (rows * band_w + cols).reshape(-1)          # static indices
-    flat = band.reshape(band_h * band_w, tc)
-    taps = jnp.take(flat, idx, axis=0)                # (th*tw*k2, tc)
-    lhs = taps.reshape(b.tile_h * b.tile_w, k2 * tc)
-    acc = jnp.dot(lhs, woff_ref[0], preferred_element_type=jnp.int32)
-    off = acc.astype(jnp.float32) * off_scale_ref[0] + off_bias_ref[0]
-    return off.reshape(b.tile_h, b.tile_w, k2, 2)
+    k, s, d, hb = b.kernel_size, b.stride, b.dilation, b.halo
+    taps = []
+    for ky in range(k):
+        for kx in range(k):
+            parts = [ch[t * s + hb + ky * d,
+                        pl.ds(hb + kx * d, b.tile_w, stride=s), :]
+                     for ch in chunks]
+            taps.extend(parts)
+    lhs = jnp.concatenate(taps, axis=-1).astype(jnp.int8)
+    acc = jnp.dot(lhs, woff_ref[0], precision=jax.lax.Precision.DEFAULT,
+                  preferred_element_type=jnp.int32)
+    return acc.astype(jnp.float32) * off_scale_ref[...] + off_bias_ref[...]
 
 
 # ---------------------------------------------------------------------------
@@ -445,6 +510,8 @@ def _forward_kernel(plan: DCLPlan, has_scale: bool, has_bias: bool, *refs):
     bias_ref = next(it) if has_bias else None
     out_ref = next(it)
     band_ref = next(it)
+    samp_ref = next(it)
+    patch_ref = next(it) if plan.contract else None
     acc_ref = next(it) if plan.contract else None
     off_scratch = next(it) if plan.fuse_offsets else None
     sem_ref = next(it)
@@ -460,6 +527,7 @@ def _forward_kernel(plan: DCLPlan, has_scale: bool, has_bias: bool, *refs):
     stager = plan.stager(x_hbm, band_ref, sem_ref, batch=i,
                          row0=j * (b.tile_h * b.stride),
                          col0=ww * (b.tile_w * b.stride))
+    chunks = [samp_ref.at[c] for c in range(samp_ref.shape[0])]
 
     if plan.contract:
         @pl.when(cc == 0)
@@ -468,47 +536,55 @@ def _forward_kernel(plan: DCLPlan, has_scale: bool, has_bias: bool, *refs):
 
     if plan.fuse_offsets:
         # Fused-offset plans stage the whole C extent (c_steps == 1), so
-        # the band is identical across the sequential M-tile axis — fetch
-        # it once per spatial tile (the scratch persists) instead of
-        # re-DMAing it every mm step, matching dcl_chain_hbm_bytes, which
-        # charges the band once per spatial tile.
+        # the band — and the offsets computed from it — are identical
+        # across the sequential M-tile axis: fetch, unpack and run the
+        # offset stage once per spatial tile (mm == 0; the scratch
+        # persists), matching dcl_chain_hbm_bytes, which charges the
+        # band once per spatial tile.
         @pl.when(mm == 0)
         def _fetch_band():
             stager.warmup()
             stager.dma(0, 0).wait()
-        band = band_ref[0]
+            unpack_band(band_ref.at[0], samp_ref)
+
+            def _offsets(t):
+                off_scratch[t] = offset_conv_row(
+                    plan, chunks, t, woff_ref, off_scale_ref, off_bias_ref)
+            for_each_row(b, _offsets)
+        off = off_scratch
     else:
         # Double buffering: the next C-chunk's band streams in underneath
-        # this chunk's gather + MXU work.
-        band = stager.stage(cc, c_steps)
+        # this chunk's sampling + MXU work.
+        unpack_band(stager.stage(cc, c_steps), samp_ref)
+        off = off_ref
 
-    if plan.fuse_offsets:
-        # The offsets are identical across the M-tile axis — compute the
-        # stage once per spatial tile (mm == 0; the axis is sequential
-        # "arbitrary", so the scratch persists) and reuse it for the
-        # remaining M-tiles instead of re-running the im2col gather +
-        # MXU contraction m_tiles times.
-        @pl.when(mm == 0)
-        def _offsets():
-            off_scratch[...] = offset_conv_stage(
-                plan, band, woff_ref, off_scale_ref, off_bias_ref)
-        off = off_scratch[...]
-    else:
-        off = off_ref[0].reshape(b.tile_h, b.tile_w, k2, 2)
-    patches = plan.sample(band, off)
+    requant = plan.band_dtype == "int8"
+
+    def _row(t):
+        taps = sample_row_taps(chunks, off, t, b)
+        if requant:
+            # The quantized datapath's patch requantization: the convex
+            # bilinear mix of int8 values stays in [-127, 127], so this
+            # is a pure round onto the activation grid.
+            taps = [jnp.round(v) for v in taps]
+        if plan.contract:
+            patch_ref[t] = jnp.concatenate(taps, axis=-1).astype(
+                patch_ref.dtype)
+        else:
+            out_ref[0, t] = jnp.stack(taps, axis=1).astype(out_ref.dtype)
+    for_each_row(b, _row)
 
     if not plan.contract:
-        # The fp32 gather returns (th, tw, k2, tc); the int8 gather
-        # returns the MXU-flat (th*tw*k2, tc) — one reshape serves both
-        # (identical row-major layout), so sample-only int8 plans emit
-        # requantized patches instead of crashing on the block shape.
-        out_ref[0] = patches.reshape(b.tile_h, b.tile_w, k2, plan.tile_c)
         return
 
-    # (th*tw, k2*tc) @ (k2*tc, tm) on the MXU — fp32 accumulation on the
-    # fp32 datapath, exact int32 on the s8 x s8 datapath.
-    lhs = patches.reshape(b.tile_h * b.tile_w, k2 * plan.tile_c)
-    acc_ref[...] += jnp.dot(lhs, w_ref[0],
+    # (th*tw, k2*tc) @ (k2*tc, tm) on the MXU — fp32 accumulation of fp32
+    # operands on the fp32 datapath, exact int32 on the s8 x s8 datapath.
+    # The precision is explicit so a caller's default_matmul_precision
+    # never reaches the kernel (Mosaic refuses fp32 contraction of int8).
+    lhs = patch_ref[...].reshape(b.tile_h * b.tile_w, k2 * plan.tile_c)
+    precision = (jax.lax.Precision.HIGHEST if plan.acc_dtype == "float32"
+                 else jax.lax.Precision.DEFAULT)
+    acc_ref[...] += jnp.dot(lhs, w_ref[0], precision=precision,
                             preferred_element_type=plan.jnp_acc_dtype())
 
     @pl.when(cc == c_steps - 1)
@@ -518,12 +594,22 @@ def _forward_kernel(plan: DCLPlan, has_scale: bool, has_bias: bool, *refs):
         if plan.epilogue == "cast":
             y = acc
         else:
-            y = acc.astype(jnp.float32) * scale_ref[0]
+            y = acc.astype(jnp.float32) * scale_ref[...]
             if has_bias:
-                y = y + bias_ref[0]
+                y = y + bias_ref[...]
             if plan.epilogue == "requant":
                 y = jnp.clip(jnp.round(y), -127, 127)
         out_ref[0] = y.reshape(b.tile_h, b.tile_w, tm).astype(out_ref.dtype)
+
+
+def compiler_params(semantics: tuple[str, ...]):
+    """Mosaic parameters of every bounded kernel: the grid semantics and
+    the scoped VMEM limit the Sec. 3.2 chooser budgets tiles against
+    (``core.tiling.VMEM_LIMIT_BYTES``), so a tile the chooser accepts is
+    a tile Mosaic accepts."""
+    from repro.core.tiling import VMEM_LIMIT_BYTES
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def forward_call(plan: DCLPlan, x_pad: Array, offsets: Array | None,
@@ -570,10 +656,15 @@ def forward_call(plan: DCLPlan, x_pad: Array, offsets: Array | None,
             and off_bias is not None
     b.check_padded(hp, wp, h_tiles, w_tiles_n)
 
+    # Offsets stream as (N*Ho, Wo, 2*K*K) row blocks: Mosaic cannot
+    # window a 4-D block whose minor dim (2*K*K) is not lane-aligned,
+    # but handles the same bytes as a 3-D block.
+    if offsets is not None:
+        offsets = offsets.reshape(n * ho, wo, 2 * k2)
     grid: tuple[int, ...]
     in_ops: list[Array] = [x_pad]
-    in_specs: list = [pl.BlockSpec(memory_space=pltpu.ANY)]
-    scratch = [plan.band_scratch()]
+    in_specs: list = [pl.BlockSpec(memory_space=pl.ANY)]
+    scratch = [plan.band_scratch(), plan.sample_scratch()]
 
     if plan.contract:
         assert w_tiles is not None
@@ -585,8 +676,8 @@ def forward_call(plan: DCLPlan, x_pad: Array, offsets: Array | None,
         if not plan.fuse_offsets:
             in_ops.append(offsets)
             in_specs.append(pl.BlockSpec(
-                (1, b.tile_h, b.tile_w, 2 * k2),
-                lambda i, j, ww, mm, cc: (i, j, ww, 0)))
+                (b.tile_h, b.tile_w, 2 * k2),
+                lambda i, j, ww, mm, cc: (i * h_tiles + j, ww, 0)))
         else:
             in_ops += [woff_tiles, off_scale, off_bias]
             in_specs += [
@@ -620,10 +711,12 @@ def forward_call(plan: DCLPlan, x_pad: Array, offsets: Array | None,
         out_specs = pl.BlockSpec((1, b.tile_h, b.tile_w, tm),
                                  lambda i, j, ww, mm, cc: (i, j, ww, mm))
         out_shape = jax.ShapeDtypeStruct((n, ho, wo, m), out_dtype)
+        scratch.append(pltpu.VMEM((b.tile_h, b.tile_w, k2 * tc),
+                                  plan.jnp_patch_dtype()))
         scratch.append(pltpu.VMEM((b.tile_h * b.tile_w, tm),
                                   plan.jnp_acc_dtype()))
         if plan.fuse_offsets:
-            scratch.append(pltpu.VMEM((b.tile_h, b.tile_w, k2, 2),
+            scratch.append(pltpu.VMEM((b.tile_h, b.tile_w, 2 * k2),
                                       jnp.float32))
         semantics = ("parallel", "parallel", "parallel", "arbitrary",
                      "arbitrary")
@@ -632,8 +725,9 @@ def forward_call(plan: DCLPlan, x_pad: Array, offsets: Array | None,
         has_scale = has_bias = False
         grid = (n, h_tiles, w_tiles_n, c_steps)
         in_ops.append(offsets)
-        in_specs.append(pl.BlockSpec((1, b.tile_h, b.tile_w, 2 * k2),
-                                     lambda i, j, ww, cc: (i, j, ww, 0)))
+        in_specs.append(pl.BlockSpec((b.tile_h, b.tile_w, 2 * k2),
+                                     lambda i, j, ww, cc: (i * h_tiles + j,
+                                                           ww, 0)))
         out_specs = pl.BlockSpec((1, b.tile_h, b.tile_w, k2, tc),
                                  lambda i, j, ww, cc: (i, j, ww, 0, cc))
         out_shape = jax.ShapeDtypeStruct((n, ho, wo, k2, c),
@@ -648,6 +742,6 @@ def forward_call(plan: DCLPlan, x_pad: Array, offsets: Array | None,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=tpu_compiler_params(dimension_semantics=semantics),
+        compiler_params=compiler_params(semantics),
         interpret=interpret,
     )(*in_ops)

@@ -79,8 +79,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import tpu_compiler_params
-from .band_pipeline import (N_BUFFERS, BandSpec, DCLPlan, corner_geometry)
+from .band_pipeline import (N_BUFFERS, BandSpec, DCLPlan, compiler_params,
+                            corner_geometry)
 
 Array = jax.Array
 
@@ -307,8 +307,8 @@ def deform_conv_bwd_zerocopy(x_pad: Array, offsets: Array, g: Array,
             dw_flush_every_step=dw_flush_every_step),
         grid=(cores, n_per_core, h_tiles, w_tiles_n, c_steps),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),      # dx seed (aliased)
-            pl.BlockSpec(memory_space=pltpu.ANY),      # whole padded input
+            pl.BlockSpec(memory_space=pl.ANY),      # dx seed (aliased)
+            pl.BlockSpec(memory_space=pl.ANY),      # whole padded input
             pl.BlockSpec((1, tile_h, tile_w, 2 * k2),
                          lambda co, b, j, ww, cc: (co * npc + b, j, ww, 0)),
             pl.BlockSpec((1, tile_h, tile_w, m),
@@ -317,7 +317,7 @@ def deform_conv_bwd_zerocopy(x_pad: Array, offsets: Array, g: Array,
                          lambda co, b, j, ww, cc: (cc, 0, 0)),
         ],
         out_specs=(
-            pl.BlockSpec(memory_space=pltpu.ANY),      # dx_pad (aliased)
+            pl.BlockSpec(memory_space=pl.ANY),      # dx_pad (aliased)
             pl.BlockSpec((1, tile_h, tile_w, 2 * k2),
                          lambda co, b, j, ww, cc: (co * npc + b, j, ww, 0)),
             pl.BlockSpec((1, 1, k2 * tc, m),
@@ -338,9 +338,9 @@ def deform_conv_bwd_zerocopy(x_pad: Array, offsets: Array, g: Array,
             pltpu.SemaphoreType.DMA((2,)),
         ],
         input_output_aliases={0: 0},
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary",
-                                 "arbitrary", "arbitrary")),
+        compiler_params=compiler_params(("parallel", "arbitrary",
+                                         "arbitrary", "arbitrary",
+                                         "arbitrary")),
         interpret=interpret,
     )(dx0, x_pad, offsets, g, w_tiles)
     # Cheap epilogue: reduce the per-core d_weights partials.  Exact at

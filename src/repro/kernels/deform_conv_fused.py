@@ -30,9 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import tpu_compiler_params
-from .band_pipeline import (BandSpec, DCLPlan, _bilinear_from_band,
-                            forward_call)
+from .band_pipeline import (BandSpec, DCLPlan, compiler_params,
+                            for_each_row, forward_call, sample_row_taps)
 
 Array = jax.Array
 
@@ -72,30 +71,29 @@ def deform_conv_fused_zerocopy(x_pad: Array, offsets: Array,
 # Legacy banded dataflow (HBM-materialized bands) — parity baseline
 # ---------------------------------------------------------------------------
 
-def _fused_kernel(bands_ref, off_ref, w_ref, out_ref, acc_ref, *,
-                  kernel_size: int, stride: int, dilation: int,
-                  offset_bound: float, tile_h: int, wo: int, c_steps: int):
-    k2 = kernel_size * kernel_size
+def _fused_kernel(bands_ref, off_ref, w_ref, out_ref, patch_ref, acc_ref,
+                  *, spec: BandSpec, c_steps: int):
     cc = pl.program_id(3)
 
     @pl.when(cc == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    off = off_ref[0].reshape(tile_h, wo, k2, 2)
-    patches = _bilinear_from_band(
-        bands_ref[0, 0], off, kernel_size=kernel_size, stride=stride,
-        dilation=dilation, offset_bound=offset_bound, tile_h=tile_h, wo=wo)
-    tc = patches.shape[-1]
+    def _row(t):
+        taps = sample_row_taps([bands_ref.at[0, 0]], off_ref.at[0], t, spec)
+        patch_ref[t] = jnp.concatenate(taps, axis=-1)
+    for_each_row(spec, _row)
     # (tile_h*wo, k2*tc) @ (k2*tc, tm) on the MXU, fp32 accumulation.
-    lhs = patches.reshape(tile_h * wo, k2 * tc)
+    lhs = patch_ref[...].reshape(spec.tile_h * spec.tile_w, -1)
     acc_ref[...] += jnp.dot(lhs, w_ref[0],
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
 
     @pl.when(cc == c_steps - 1)
     def _flush():
         tm = out_ref.shape[-1]
-        out_ref[0] = acc_ref[...].reshape(tile_h, wo, tm).astype(out_ref.dtype)
+        out_ref[0] = acc_ref[...].reshape(
+            spec.tile_h, spec.tile_w, tm).astype(out_ref.dtype)
 
 
 @functools.partial(
@@ -129,9 +127,11 @@ def deform_conv_fused_banded(bands: Array, offsets: Array, w_tiles: Array, *,
 
     return pl.pallas_call(
         functools.partial(
-            _fused_kernel, kernel_size=kernel_size, stride=stride,
-            dilation=dilation, offset_bound=offset_bound, tile_h=tile_h,
-            wo=wo, c_steps=c_steps),
+            _fused_kernel,
+            spec=BandSpec(kernel_size=kernel_size, stride=stride,
+                          dilation=dilation, offset_bound=offset_bound,
+                          tile_h=tile_h, tile_w=wo),
+            c_steps=c_steps),
         grid=(n, n_tiles, m // tm, c_steps),
         in_specs=[
             pl.BlockSpec((1, 1, band_h, w_pad, tc),
@@ -144,9 +144,9 @@ def deform_conv_fused_banded(bands: Array, offsets: Array, w_tiles: Array, *,
         out_specs=pl.BlockSpec((1, tile_h, wo, tm),
                                lambda i, j, mm, cc: (i, j, 0, mm)),
         out_shape=jax.ShapeDtypeStruct((n, ho, wo, m), bands.dtype),
-        scratch_shapes=[pltpu.VMEM((tile_h * wo, tm), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((tile_h, wo, k2 * tc), jnp.float32),
+                        pltpu.VMEM((tile_h * wo, tm), jnp.float32)],
+        compiler_params=compiler_params(("parallel", "parallel",
+                                         "parallel", "arbitrary")),
         interpret=interpret,
     )(bands, offsets, w_tiles)

@@ -13,10 +13,11 @@ Precision split (CoDeNet / Xu et al. 2021 — deformable conv tolerates
 * the **band DMA** streams symmetric-int8 activations HBM -> VMEM
   through the same double-buffered pipeline as the fp32 kernel
   (``band_pipeline.BandStager`` — one geometry, two dtypes);
-* **bilinear coefficients are fp32**: corner indices/fractions come
-  from the shared ``corner_geometry`` (address generation is always
-  full precision), the int8 corner values combine in fp32, and the
-  result is re-rounded onto the activation grid.  A bilinear mix is
+* **bilinear coefficients are fp32**: the shared shifted-window
+  sampler (``band_pipeline.sample_row_taps``) forms corner positions
+  and fractions in fp32 (address generation is always full precision),
+  the int8 corner values combine in fp32, and the result is re-rounded
+  onto the activation grid.  A bilinear mix is
   convex, so the combination of in-range int8 values is in range —
   requantization is a pure round, never a clip, and the patch scale is
   exactly the activation scale;
@@ -31,7 +32,7 @@ Precision split (CoDeNet / Xu et al. 2021 — deformable conv tolerates
   quantized tensor never round-trips HBM at fp32.
 
 The chain kernel additionally fuses the **offset-conv stage**
-(``band_pipeline.offset_conv_stage``): the offset conv's undeformed
+(``band_pipeline.offset_conv_row``): the offset conv's undeformed
 taps are a static-index subset of the staged Eq. 6 band, so the raw
 offsets are produced in-kernel from the int8 band + quantized offset
 weights — no separate fp32 offset pass and no offsets in HBM at all.
@@ -50,8 +51,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .band_pipeline import (  # noqa: F401  (re-export)
-    BandSpec, DCLPlan, _bilinear_int8_from_band, forward_call)
+from .band_pipeline import BandSpec, DCLPlan, forward_call
 
 Array = jax.Array
 

@@ -20,27 +20,27 @@ index is in-band by construction.
 
 The zero-copy kernel itself is emitted by ``band_pipeline.forward_call``
 from a contraction-free ``DCLPlan`` (``tile_m=None``): the shared
-double-buffered band stager + the fp32 bilinear gather, with the
-patches as the output.  The geometry helpers (``band_geometry``,
-``corner_geometry``, ``_bilinear_from_band``) live in ``band_pipeline``
-and are re-exported here for compatibility.
+double-buffered band stager + the shifted-window bilinear sampler, with
+the patches as the output.  The geometry helper ``band_geometry`` lives
+in ``band_pipeline`` and is re-exported here.
 
 ``deform_sample_banded`` (legacy) consumes the HBM-materialized
 overlapping bands of ``kernels.plan.pad_and_band`` through a BlockSpec
 pipeline — kept as the parity/regression baseline (no in-kernel DMA, so
-it does not go through the band stager).
+it does not go through the band stager; it samples with the same
+``band_pipeline.sample_row_taps``).
 """
 from __future__ import annotations
 
 import functools
 
 import jax
+import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._compat import tpu_compiler_params
-from .band_pipeline import (  # noqa: F401  (re-exports)
-    N_BUFFERS, BandSpec, DCLPlan, _bilinear_from_band, band_geometry,
-    corner_geometry, forward_call, make_band_dma)
+from .band_pipeline import (  # noqa: F401  (re-export)
+    BandSpec, DCLPlan, band_geometry, compiler_params, for_each_row,
+    forward_call, sample_row_taps)
 
 Array = jax.Array
 
@@ -73,14 +73,11 @@ def deform_sample_zerocopy(x_pad: Array, offsets: Array, *, kernel_size: int,
 # Legacy banded dataflow (HBM-materialized bands) — parity baseline
 # ---------------------------------------------------------------------------
 
-def _sample_kernel(bands_ref, off_ref, out_ref, *, kernel_size: int,
-                   stride: int, dilation: int, offset_bound: float,
-                   tile_h: int, wo: int):
-    k2 = kernel_size * kernel_size
-    off = off_ref[0].reshape(tile_h, wo, k2, 2)
-    out_ref[0] = _bilinear_from_band(
-        bands_ref[0, 0], off, kernel_size=kernel_size, stride=stride,
-        dilation=dilation, offset_bound=offset_bound, tile_h=tile_h, wo=wo)
+def _sample_kernel(bands_ref, off_ref, out_ref, *, spec: BandSpec):
+    def _row(t):
+        taps = sample_row_taps([bands_ref.at[0, 0]], off_ref.at[0], t, spec)
+        out_ref[0, t] = jnp.stack(taps, axis=1).astype(out_ref.dtype)
+    for_each_row(spec, _row)
 
 
 @functools.partial(
@@ -106,9 +103,10 @@ def deform_sample_banded(bands: Array, offsets: Array, *, kernel_size: int,
 
     return pl.pallas_call(
         functools.partial(
-            _sample_kernel, kernel_size=kernel_size, stride=stride,
-            dilation=dilation, offset_bound=offset_bound, tile_h=tile_h,
-            wo=wo),
+            _sample_kernel,
+            spec=BandSpec(kernel_size=kernel_size, stride=stride,
+                          dilation=dilation, offset_bound=offset_bound,
+                          tile_h=tile_h, tile_w=wo)),
         grid=(n, n_tiles, c // tc),
         in_specs=[
             pl.BlockSpec((1, 1, band_h, w_pad, tc),
@@ -119,7 +117,7 @@ def deform_sample_banded(bands: Array, offsets: Array, *, kernel_size: int,
         out_specs=pl.BlockSpec((1, tile_h, wo, k2, tc),
                                lambda i, j, cc: (i, j, 0, 0, cc)),
         out_shape=jax.ShapeDtypeStruct((n, ho, wo, k2, c), bands.dtype),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel")),
+        compiler_params=compiler_params(("parallel", "parallel",
+                                         "parallel")),
         interpret=interpret,
     )(bands, offsets)

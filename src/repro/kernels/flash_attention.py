@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import tpu_compiler_params
 
 Array = jax.Array
 
@@ -128,7 +127,7 @@ def flash_attention_bh(q: Array, k: Array, v: Array, *, causal: bool = True,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -145,7 +144,8 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
     Returns (B, Sq, KV, G, Dh).
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        from .ops import default_interpret
+        interpret = default_interpret()
     b, sq, kv, g, dh = q.shape
     sk = k.shape[1]
     qh = q.transpose(0, 2, 3, 1, 4).reshape(b * kv * g, sq, dh)

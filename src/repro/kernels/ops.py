@@ -30,8 +30,9 @@ Bounded kernels support two dataflows (``dataflow=``):
   the kernel runs.  Kept as the parity baseline; see EXPERIMENTS.md
   §Perf for the modeled traffic difference.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only); on
-a real TPU backend it auto-disables.
+``interpret`` defaults to the lowering platform of ``launch.platform``:
+Mosaic on a TPU backend, Pallas interpret mode elsewhere (the CPU
+tests).
 
 The bounded ``deform_conv`` path is differentiable: it is wrapped in a
 ``jax.custom_vjp`` whose backward is the fused zero-copy kernel of
@@ -79,7 +80,6 @@ import logging
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.deform_conv import DCLConfig, sample_patches
@@ -117,11 +117,13 @@ def default_interpret() -> bool:
 # Graceful degradation (PR 6).
 #
 # Argument validation (bad tiles, missing scales, unknown modes) is
-# hoisted into the un-jitted public wrappers and still RAISES — a wrong
+# hoisted into the un-jitted public wrappers and always RAISES — a wrong
 # call is a caller bug, and the friendly ValueErrors are part of the
 # API.  Failures past validation — plan resolution, the emitter, kernel
-# lowering, or an injected dispatch fault — are bounded-path problems a
-# correct XLA graph can serve, so the wrappers fall back to the
+# lowering, or an injected dispatch fault — raise too by default: a
+# silent switch to the XLA reference would hide a kernel that does not
+# run on the device.  Callers that prefer to be served anyway opt in
+# with ``degradation_scope(True)``: the wrappers then fall back to the
 # reference path (``ref.deform_conv_fused_ref`` / the fake-quant
 # oracles of ``repro.quant.qat``) with exactly one warning per
 # (entry, precision) on the ``repro.resilience`` logger.  The ladder:
@@ -138,7 +140,7 @@ def default_interpret() -> bool:
 _log = logging.getLogger("repro.resilience")
 
 _dispatch_hook = None
-_degrade_enabled = True
+_degrade_enabled = False
 _FALLBACK_WARNED: set = set()
 
 
@@ -168,8 +170,8 @@ def get_dispatch_hook():
 
 def set_degradation(enabled: bool):
     """Toggle the reference fallback; returns the previous setting.
-    With degradation off, post-validation failures raise (the strict
-    mode the parity test-suites run under when they WANT the kernel)."""
+    Off (the default), post-validation failures raise; on, they are
+    served by the reference path after one warning."""
     global _degrade_enabled
     prev, _degrade_enabled = _degrade_enabled, bool(enabled)
     return prev
@@ -187,9 +189,9 @@ def degradation_scope(enabled: bool):
     The serving engine wraps each batch in ``degradation_scope(False)``
     so kernel failures surface as exceptions it converts into its OWN
     per-request ladder (retry, then drop a rung, recorded in request
-    telemetry) instead of this module's process-global warn-once
-    fallback — two engines in one process never share degradation
-    state (docs/serving.md)."""
+    telemetry) even inside a caller's ``degradation_scope(True)`` —
+    two engines in one process never share degradation state
+    (docs/serving.md)."""
     prev = set_degradation(enabled)
     try:
         yield
@@ -452,10 +454,10 @@ _deform_conv_bounded.defvjp(_deform_conv_bounded_fwd,
 def _deform_conv_sharded(spec: _DCSpec, shard: _ShardSpec, x: Array,
                          offsets: Array, w: Array) -> Array:
     pb = shard.pspec(4)
-    fn = shard_map(functools.partial(_plan.bounded_forward, spec),
-                   mesh=shard.mesh,
-                   in_specs=(pb, pb, P(None, None, None)),
-                   out_specs=pb, check_rep=False)
+    fn = jax.shard_map(functools.partial(_plan.bounded_forward, spec),
+                       mesh=shard.mesh,
+                       in_specs=(pb, pb, P(None, None, None)),
+                       out_specs=pb, check_vma=False)
     return fn(x, offsets, w)
 
 
@@ -474,9 +476,9 @@ def _deform_conv_sharded_bwd(spec, shard, res, gy):
         # cotangent is the sum of every shard's partial d_weights.
         return dx, doff, jax.lax.psum(dw, shard.axes)
 
-    fn = shard_map(body, mesh=shard.mesh,
-                   in_specs=(pb, pb, rep_w, pb),
-                   out_specs=(pb, pb, rep_w), check_rep=False)
+    fn = jax.shard_map(body, mesh=shard.mesh,
+                       in_specs=(pb, pb, rep_w, pb),
+                       out_specs=(pb, pb, rep_w), check_vma=False)
     return fn(x, offsets, w, gy)
 
 

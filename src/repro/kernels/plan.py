@@ -30,7 +30,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.core.tiling import LayerShape, choose_kernel_tiles, out_hw
+from repro.core.tiling import (LayerShape, choose_kernel_tiles, out_hw,
+                               staged_width)
 from .band_pipeline import band_geometry
 from .deform_conv_bwd import deform_conv_bwd_zerocopy
 from .deform_conv_fused import (deform_conv_fused_banded,
@@ -396,6 +397,7 @@ def pad_zerocopy(x: Array, *, kernel_size: int, stride: int, dilation: int,
     _, band_w = band_geometry(kernel_size=kernel_size, stride=stride,
                               dilation=dilation, offset_bound=offset_bound,
                               tile_h=tile_w)
+    band_w = staged_width(band_w)
     h_tiles = ho // tile_h
     w_tiles = wo // tile_w
     p0 = pad + hb
@@ -617,19 +619,18 @@ def chain_forward(x: Array, w: Array, w_offset: Array, b_offset: Array,
     # The chooser's VMEM feasibility was evaluated at its own free
     # tile_c; chaining pins tile_c = C, so re-check the working set the
     # kernel will actually allocate (single-buffer full-C int8 band +
-    # offset-weight block) and shrink the spatial tiles until it fits.
-    from repro.core.tiling import (TileConfig, V5E_VMEM_BYTES,
+    # offset-conv stage) and shrink the spatial tiles until it fits.
+    from repro.core.tiling import (TileConfig, VMEM_LIMIT_BYTES,
                                    zerocopy_vmem_bytes)
 
     def _chain_vmem(th_, tw_):
-        base = zerocopy_vmem_bytes(
+        return zerocopy_vmem_bytes(
             LayerShape(h=h, w=w_in, c_in=c, c_out=m,
                        kernel_size=kernel_size, stride=stride,
                        offset_bound=offset_bound),
             TileConfig(th_, tw_, c, tm), dilation=dilation,
-            bytes_per_elem=1, aux_bytes_per_elem=4)
-        return base + k2 * c * 2 * k2          # + int8 offset-conv block
-    while _chain_vmem(th, tw) > V5E_VMEM_BYTES and (th > 1 or tw > 1):
+            bytes_per_elem=1, aux_bytes_per_elem=4, fuse_offsets=True)
+    while _chain_vmem(th, tw) > VMEM_LIMIT_BYTES and (th > 1 or tw > 1):
         if tw > 1:
             tw = max(1, tw // 2)
         else:

@@ -30,6 +30,8 @@ stale lowering.
 from __future__ import annotations
 
 import contextlib
+import os
+import pathlib
 
 PLATFORMS = ("tpu", "interpret", "xla_ref")
 
@@ -60,12 +62,8 @@ def _invalidate_lowering_caches() -> None:
         resolve_tiles.cache_clear()
     except Exception:  # noqa: BLE001 — plan not importable yet is fine
         pass
-    try:
-        import jax
-        if hasattr(jax, "clear_caches"):
-            jax.clear_caches()
-    except Exception:  # noqa: BLE001
-        pass
+    import jax
+    jax.clear_caches()
 
 
 def set_platform(name: str) -> str:
@@ -104,3 +102,29 @@ def platform_scope(name: str):
         yield
     finally:
         set_platform(prev)
+
+
+# The checkout's own compile-cache directory (listed in .gitignore): a
+# fixed path, so a rerun from the same checkout finds its entries.
+REPO_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory: ``$JAX_COMPILATION_CACHE_DIR`` when the environment sets
+    it, else ``REPO_CACHE_DIR``.  Called by the launchers and
+    ``chip_smoke.py`` before their first compile."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(REPO_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def device_summary() -> dict:
+    """The device this process runs on, as JAX reports it: platform,
+    device kind and device count — printed by every launcher so no
+    result is read against the wrong device."""
+    import jax
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices())}

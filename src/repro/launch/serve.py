@@ -13,8 +13,16 @@ per-request degradation ladder live:
         --requests 12 [--buckets 64,128] [--quant int8_chain] \
         [--deadline 30] [--shed-policy reject_new] [--telemetry OUT.json]
 
-Reduced config by default (CPU container); optionally restores params
-from a checkpoint produced by ``repro.launch.train``.
+The paper's production path at published widths (ResNet-50, 12 DCLs,
+B=2, 512x512 images) on a TPU:
+
+    PYTHONPATH=src python -m repro.launch.serve \
+        --arch resnet50_dcn_bounded --full --buckets 512
+
+Reduced same-family config unless ``--full``; weights come from a
+seeded init or, with ``--ckpt``, from a checkpoint produced by
+``repro.launch.train``.  Every result line is read against the device
+line printed first.
 """
 from __future__ import annotations
 
@@ -25,6 +33,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.platform import device_summary, enable_compile_cache
 from repro.models import registry as reg
 from repro.models.registry import reduced_config
 from repro.models.resnet_dcn import ResNetDCNConfig
@@ -39,7 +48,8 @@ def serve_detection(cfg: ResNetDCNConfig, args) -> None:
     if cfg.offset_bound is None:
         cfg = dataclasses.replace(cfg, offset_bound=2.0)
     cfg = dataclasses.replace(cfg, use_kernel=True)
-    buckets = tuple(int(b) for b in args.buckets.split(","))
+    buckets = tuple(int(b) for b in args.buckets.split(",")) \
+        if args.buckets else (cfg.img_size if args.full else 64,)
     params = R.init_params(jax.random.PRNGKey(0), cfg)
     if args.ckpt:
         from repro.checkpoint import restore_checkpoint
@@ -76,7 +86,7 @@ def serve_detection(cfg: ResNetDCNConfig, args) -> None:
     lats = sorted(r.latency_s() for r in ok)
     print(f"served {len(ok)}/{len(engine.completed)} requests in "
           f"{engine.steps} batched steps ({dt:.1f}s, "
-          f"{len(ok) / max(dt, 1e-9):.2f} req/s on CPU interpret)")
+          f"{len(ok) / max(dt, 1e-9):.2f} req/s, compiles included)")
     if lats:
         print(f"  p50 latency {lats[len(lats) // 2] * 1e3:.0f} ms, "
               f"max {lats[-1] * 1e3:.0f} ms")
@@ -96,8 +106,11 @@ def main() -> None:
     ap.add_argument("--max-new-tokens", type=int, default=16)
     ap.add_argument("--ckpt", default=None)
     # DCL detection engine knobs
-    ap.add_argument("--buckets", default="64",
-                    help="comma-separated square shape buckets")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the FULL (published-width) config")
+    ap.add_argument("--buckets", default=None,
+                    help="comma-separated square shape buckets (default: "
+                         "the config's image size with --full, else 64)")
     ap.add_argument("--quant", default="int8_chain", choices=LADDER)
     ap.add_argument("--deadline", type=float, default=None,
                     help="per-request deadline in seconds")
@@ -108,8 +121,10 @@ def main() -> None:
                     help="write engine telemetry JSON here")
     args = ap.parse_args()
 
+    cache_dir = enable_compile_cache()
+    print(f"device {device_summary()}, compile cache {cache_dir}")
     arch = reg.get(args.arch)
-    cfg = reduced_config(arch)
+    cfg = arch.config if args.full else reduced_config(arch)
     if isinstance(cfg, ResNetDCNConfig):
         serve_detection(cfg, args)
         return
@@ -145,8 +160,8 @@ def main() -> None:
     dt = time.monotonic() - t0
     toks = sum(len(r.output) for r in engine.completed)
     print(f"served {len(engine.completed)} requests / {toks} tokens in "
-          f"{steps} batched steps ({dt:.1f}s, {toks / dt:.1f} tok/s "
-          f"on CPU interpret)")
+          f"{steps} batched steps ({dt:.1f}s, {toks / dt:.1f} tok/s, "
+          f"compiles included)")
     for r in engine.completed[:3]:
         print(f"  req {r.uid}: {r.output[:8]}...")
 

@@ -22,6 +22,7 @@ from repro.data import (DetectionDataConfig, LMDataConfig, detection_batch,
                         lm_batch)
 from repro.distributed.sharding import use_rules
 from repro.launch.mesh import make_host_mesh
+from repro.launch.platform import device_summary, enable_compile_cache
 from repro.models import registry as reg
 from repro.models.registry import reduced_config
 from repro.models.resnet_dcn import ResNetDCNConfig
@@ -45,6 +46,8 @@ def main() -> None:
     ap.add_argument("--seq-len", type=int, default=64)
     args = ap.parse_args()
 
+    cache_dir = enable_compile_cache()
+    print(f"device {device_summary()}, compile cache {cache_dir}")
     arch = reg.get(args.arch)
     cfg = arch.config if args.full else reduced_config(arch)
     mesh = make_host_mesh()

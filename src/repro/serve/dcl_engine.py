@@ -149,7 +149,10 @@ class DCLServingEngine:
     """See module docstring.  ``clock``/``sleep`` are injectable for
     deterministic deadline and backoff tests; ``step_hook(step, ctx)``
     and ``admit_hook(request)`` are the chaos seams
-    (``resilience.ChaosHooks.serve_step_hook`` / ``admit_hook``)."""
+    (``resilience.ChaosHooks.serve_step_hook`` / ``admit_hook``).
+    ``tap(name, x)`` sees every DCL block's input (``name``) and output
+    (``name + "/out"``) of each served batch, as ``R.forward`` taps
+    them — how a caller checks the served layers against a reference."""
 
     def __init__(self, params, model_cfg: R.ResNetDCNConfig,
                  serve_cfg: DCLServeConfig, *,
@@ -158,6 +161,7 @@ class DCLServingEngine:
                  sleep: Callable[[float], None] = time.sleep,
                  step_hook: Callable[[int, dict], None] | None = None,
                  admit_hook: Callable[[DetRequest], DetRequest] | None = None,
+                 tap: Callable[[str, Any], None] | None = None,
                  registry: MetricsRegistry | None = None,
                  tracer: Tracer | None = None):
         self.params = params
@@ -166,6 +170,7 @@ class DCLServingEngine:
         self._sleep = sleep
         self.step_hook = step_hook
         self.admit_hook = admit_hook
+        self.tap = tap
 
         # Observability (ISSUE 8).  Each engine defaults to its OWN
         # registry — two engines in one process never share counters,
@@ -430,7 +435,7 @@ class DCLServingEngine:
             clock=self.clock)
         with mesh_ctx, ops.dispatch_hook_scope(rec), \
                 ops.degradation_scope(False):
-            out, _ = R.forward(self.params, cfg, x,
+            out, _ = R.forward(self.params, cfg, x, tap=self.tap,
                                quant_scales=self.scale_table)
         return out
 

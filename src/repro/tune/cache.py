@@ -197,12 +197,8 @@ def install_tile_cache(cache) -> TileCache | None:
         resolve_tiles.cache_clear()
     except Exception:  # noqa: BLE001
         pass
-    try:
-        import jax
-        if hasattr(jax, "clear_caches"):
-            jax.clear_caches()
-    except Exception:  # noqa: BLE001
-        pass
+    import jax
+    jax.clear_caches()
     return prev
 
 

@@ -29,6 +29,7 @@ if __name__ == "__main__":       # subprocess mode: force the devices
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 CHAOS_SEED = 20260808
 TOTAL_STEPS = 8
@@ -48,7 +49,8 @@ def run_checks() -> dict:
     from repro.resilience import ChaosHooks, FaultPlan
     from repro.train import Trainer, TrainerConfig
 
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = jax.make_mesh((4,), ("data",),
+                         axis_types=(AxisType.Auto,))
     cfg = R.ResNetDCNConfig(
         stage_sizes=(1, 1, 1, 1), widths=(16, 32, 64, 128), stem_width=8,
         num_dcn=2, num_classes=4, img_size=32, offset_bound=2.0,

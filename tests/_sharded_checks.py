@@ -32,6 +32,7 @@ if __name__ == "__main__":       # subprocess mode: force the devices
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 import numpy as np
 
 
@@ -61,7 +62,8 @@ def run_checks() -> dict:
     from repro.models import resnet_dcn as R
     from repro.models.layers import dcl_apply, dcl_def, init_tree
 
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = jax.make_mesh((4,), ("data",),
+                         axis_types=(AxisType.Auto,))
     out: dict = {"device_count": jax.device_count()}
 
     # -- 1. raw kernel-path grad parity, sharded vs XLA reference ------

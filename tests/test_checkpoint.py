@@ -63,8 +63,9 @@ def test_async_manager_and_wait(tmp_path):
 def test_elastic_restore_with_sharding(tmp_path):
     """Restore with explicit shardings (the elastic path: the restart
     mesh may differ from the save mesh)."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(AxisType.Auto,))
     tree = {"w": jnp.arange(16, dtype=jnp.float32).reshape(4, 4)}
     save_checkpoint(tmp_path, 1, tree)
     sh = {"w": NamedSharding(mesh, P("data", None))}

@@ -45,12 +45,12 @@ def test_error_feedback_preserves_average_gradient():
 
 
 def test_compressed_psum_on_mesh():
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import Mesh, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    from jax.sharding import AxisType, PartitionSpec as P
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(AxisType.Auto,))
     x = jax.random.normal(jax.random.PRNGKey(1), (8,))
 
-    f = shard_map(
+    f = jax.shard_map(
         functools.partial(compressed_psum, axis_name="data"),
         mesh=mesh, in_specs=P(None), out_specs=P(None))
     got = f(x)

@@ -34,12 +34,12 @@ def model():
 
 @pytest.fixture
 def clean_dispatch():
-    ops.set_dispatch_hook(None)
-    ops.set_degradation(True)
+    prev_hook = ops.set_dispatch_hook(None)
+    prev_deg = ops.set_degradation(True)
     ops.reset_fallback_warnings()
     yield
-    ops.set_dispatch_hook(None)
-    ops.set_degradation(True)
+    ops.set_dispatch_hook(prev_hook)
+    ops.set_degradation(prev_deg)
     ops.reset_fallback_warnings()
 
 
@@ -135,6 +135,34 @@ def test_int8_chain_default_serves_and_reports_rung(model):
     assert set(tel["plans"][str(BUCKET)]) == {"s2b0", "s3b0"}
     assert {"hits", "misses", "size"} <= set(tel["plan_cache"])
     assert all(r["outcome"] in OUTCOMES for r in tel["requests"])
+
+
+def test_tap_sees_every_served_dcl_layer(model):
+    """The engine's ``tap`` receives each DCL's input and output of the
+    served batch; re-running a layer on its tapped input reproduces the
+    tapped output (the served layers can be checked one by one)."""
+    from repro.models.layers import dcl_apply
+    from repro.serve import bucket_layer_dims
+    cfg, params, table = model
+    seen = []
+    eng = DCLServingEngine(params, cfg,
+                           DCLServeConfig(buckets=(BUCKET,), slots=2),
+                           scale_table=table,
+                           tap=lambda n, a: seen.append((n, a)))
+    r = eng.submit(_img(20))
+    eng.run_until_drained()
+    assert r.outcome == "ok" and r.ladder == "int8_chain"
+    taps = dict(seen)
+    assert [n for n, _ in seen] == ["s2b0", "s2b0/out", "s3b0", "s3b0/out"]
+    dims = bucket_layer_dims(cfg, BUCKET)
+    for name in ("s2b0", "s3b0"):
+        y, _ = dcl_apply(params[name]["dcl"], taps[name],
+                         stride=dims[name]["stride"],
+                         offset_bound=cfg.offset_bound, use_kernel=True,
+                         quant="int8_chain", quant_scales=table[name])
+        assert taps[f"{name}/out"].shape == y.shape
+        assert np.array_equal(np.asarray(taps[f"{name}/out"]),
+                              np.asarray(y.dequantize(cfg.dtype)))
 
 
 # -- deadlines ------------------------------------------------------------

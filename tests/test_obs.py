@@ -242,7 +242,8 @@ def test_dispatch_recorder_times_real_kernel_dispatch():
 
 def test_dispatch_recorder_chains_and_survives_chaos_raise():
     """next_hook (the chaos seam) runs FIRST; its raise aborts the
-    dispatch before any timing starts, and ops degrades as before."""
+    dispatch before any timing starts, and ops degrades (opted in with
+    ``degradation_scope(True)``) as before."""
     from repro.kernels import ops
 
     calls = []
@@ -260,7 +261,7 @@ def test_dispatch_recorder_chains_and_survives_chaos_raise():
     rec = DispatchRecorder(registry=reg, next_hook=chaos_hook)
     ops._FALLBACK_WARNED.discard(("deform_conv", "fp32"))
     try:
-        with ops.dispatch_hook_scope(rec):
+        with ops.dispatch_hook_scope(rec), ops.degradation_scope(True):
             out = ops.deform_conv(x, offs, wgt, offset_bound=2.0)
     finally:
         ops._FALLBACK_WARNED.discard(("deform_conv", "fp32"))

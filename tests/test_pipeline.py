@@ -11,6 +11,7 @@ import sys
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 import numpy as np
 import pytest
 
@@ -20,7 +21,8 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 def test_single_stage_degenerates_to_sequential():
-    mesh = jax.make_mesh((1,), ("stage",))
+    mesh = jax.make_mesh((1,), ("stage",),
+                         axis_types=(AxisType.Auto,))
     d = 8
     ws = jax.random.normal(jax.random.PRNGKey(0), (1, d, d)) / jnp.sqrt(d)
 

@@ -163,11 +163,21 @@ def test_dtype_budget_monotone_tiles(budget):
     """Under a binding VMEM budget the chooser's Eq. 6 band working set
     (tile_h * tile_w * tile_c elements) must widen monotonically as
     bytes-per-element shrink — int8 packs 4x the band of fp32 into the
-    same VMEM."""
+    same VMEM.  At 1 MiB fp32 admits no tile at all: its smallest
+    kernel tile (whole 128-lane channel tiles, double-buffered weight
+    blocks) needs more, so the chooser must refuse it (0 elements)
+    while bf16 and int8 still fit."""
     from repro.core.tiling import LayerShape, choose_kernel_tiles
     shape = LayerShape(h=64, w=64, c_in=128, c_out=128, offset_bound=2.0)
     elems = {}
     for dtype in ("fp32", "bf16", "int8"):
+        if dtype == "fp32" and budget == 1 << 20:
+            with pytest.raises(ValueError, match="no zero-copy tile "
+                                                 "configuration fits"):
+                choose_kernel_tiles(shape, dtype=dtype, objective="forward",
+                                    vmem_budget=budget)
+            elems[dtype] = 0
+            continue
         kt = choose_kernel_tiles(shape, dtype=dtype, objective="forward",
                                  vmem_budget=budget)
         elems[dtype] = kt.tile_h * kt.tile_w * kt.tile_c
