@@ -1,0 +1,17 @@
+"""Published peaks of each chip, keyed by JAX's ``device_kind``
+(``bench/peaks.json``, with its source).  A kind that is not in the
+table is an error, never a default."""
+from __future__ import annotations
+
+import json
+import pathlib
+
+TABLE = pathlib.Path(__file__).with_name("peaks.json")
+
+
+def lookup(device_kind: str) -> dict:
+    table = json.loads(TABLE.read_text())
+    if device_kind not in table:
+        raise KeyError(f"no peaks for device kind {device_kind!r} in "
+                       f"{TABLE.name} (have {sorted(table)})")
+    return table[device_kind]
