@@ -11,7 +11,7 @@ Summarizes any of the three artifact families:
   per-label histogram latency table (count / p50 / p99 / mean);
 * ``--divergence file.json`` — a divergence report
   (:meth:`DivergenceTracker.report`), bare or under a ``"divergence"``
-  key (``BENCH_kernels.json``, serve telemetry): per-dispatch-key
+  key (``BENCH_kernels.json``): per-dispatch-key
   aggregates and the modeled-vs-measured ratio pairs, anomalies
   flagged.
 
@@ -55,7 +55,7 @@ def load_metrics(path) -> dict:
 
 def load_divergence(path) -> dict:
     """A divergence report — bare, or under ``"divergence"`` of
-    ``BENCH_kernels.json`` / a serve telemetry dump."""
+    ``BENCH_kernels.json``."""
     doc = json.loads(pathlib.Path(path).read_text())
     if "dispatches" in doc or "pairs" in doc:
         return doc

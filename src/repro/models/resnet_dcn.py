@@ -17,6 +17,17 @@ fused kernel (``repro.kernels.ops.deform_conv``) — including under
 (``kernels.deform_conv_bwd``), so ``train_loss`` with a kernel-path
 config trains through the zero-copy Pallas dataflow in both
 directions.  The pure-JAX path remains the parity reference.
+
+Run eagerly (the serving engine), the forward opens a program span
+(``repro.obs.trace``) around each piece of work: ``model/stem``,
+``model/block``, ``model/head``, and inside them ``model/conv`` (an
+XLA conv with its weight cast), ``model/gn`` and ``model/dcl`` (the
+DCL with its int8 dequantize), each with a ``layer`` attribute such as
+``s2b3/conv1``.  A profiler session then puts every device program
+down to the layer that launched it.  When the images, the params or
+the scale table are being traced (``jax.jit``, ``jax.grad``,
+``jax.vmap``) the forward opens no span: it would time tracing, not
+work.
 """
 from __future__ import annotations
 
@@ -27,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.deform_conv import conv2d
+from repro.obs.trace import NOOP_SPAN, get_tracer
 from .layers import ParamDef, dcl_apply, dcl_def
 
 Array = jax.Array
@@ -96,6 +108,30 @@ def group_norm(x: Array, params, *, groups: int = GN_GROUPS,
     return (y * params["scale"] + params["bias"]).astype(x.dtype)
 
 
+def _no_span(name: str, layer: str):
+    return NOOP_SPAN
+
+
+def _spans(*inputs):
+    """``span(name, layer)`` for one forward: a program span around
+    eager work, or none at all if any of ``inputs`` is being traced."""
+    if any(isinstance(a, jax.core.Tracer)
+           for a in jax.tree_util.tree_leaves(inputs)):
+        return _no_span
+    tracer = get_tracer()
+    return lambda name, layer: tracer.span(name, layer=layer)
+
+
+def _conv(span, x, w, layer: str, **kw):
+    with span("model/conv", layer):
+        return conv2d(x, w.astype(x.dtype), **kw)
+
+
+def _gn(span, x, params, layer: str):
+    with span("model/gn", layer):
+        return group_norm(x, params)
+
+
 def _dcl_def(cin, cout, k=3):
     return dcl_def(cin, cout, k)
 
@@ -156,31 +192,36 @@ def _apply_dcl(params, x: Array, cfg: ResNetDCNConfig, *, stride=1,
 
 
 def _apply_block(params, x: Array, cfg: ResNetDCNConfig, *, stride: int,
-                 is_dcn: bool, name: str = "", tap=None, quant_scales=None):
-    h = conv2d(x, params["conv1"].astype(x.dtype))
-    h = jax.nn.relu(group_norm(h, params["gn1"]))
-    o_max = None
-    if is_dcn:
-        if tap is not None:
-            tap(name, h)
-        h, o_max = _apply_dcl(params["dcl"], h, cfg, stride=stride,
-                              quant_scales=quant_scales)
-        if hasattr(h, "dequantize"):
-            # int8_chain emission: the DCL output crossed HBM as int8
-            # (QTensor); the GroupNorm consumer decodes it here — the
-            # only fp32 materialization of the tensor.
-            h = h.dequantize(cfg.dtype)
-        if tap is not None:
-            tap(f"{name}/out", h)
-    else:
-        h = conv2d(h, params["conv2"].astype(x.dtype), stride=stride)
-    h = jax.nn.relu(group_norm(h, params["gn2"]))
-    h = conv2d(h, params["conv3"].astype(x.dtype))
-    h = group_norm(h, params["gn3"])
-    if "proj" in params:
-        x = conv2d(x, params["proj"].astype(x.dtype), stride=stride)
-        x = group_norm(x, params["gn_proj"])
-    return jax.nn.relu(x + h), o_max
+                 is_dcn: bool, name: str = "", tap=None, quant_scales=None,
+                 span=_no_span):
+    with span("model/block", name):
+        h = _conv(span, x, params["conv1"], f"{name}/conv1")
+        h = jax.nn.relu(_gn(span, h, params["gn1"], f"{name}/gn1"))
+        o_max = None
+        if is_dcn:
+            if tap is not None:
+                tap(name, h)
+            with span("model/dcl", f"{name}/dcl"):
+                h, o_max = _apply_dcl(params["dcl"], h, cfg, stride=stride,
+                                      quant_scales=quant_scales)
+                if hasattr(h, "dequantize"):
+                    # int8_chain emission: the DCL output crossed HBM as
+                    # int8 (QTensor); the GroupNorm consumer decodes it
+                    # here — the only fp32 materialization of the tensor.
+                    h = h.dequantize(cfg.dtype)
+            if tap is not None:
+                tap(f"{name}/out", h)
+        else:
+            h = _conv(span, h, params["conv2"], f"{name}/conv2",
+                      stride=stride)
+        h = jax.nn.relu(_gn(span, h, params["gn2"], f"{name}/gn2"))
+        h = _conv(span, h, params["conv3"], f"{name}/conv3")
+        h = _gn(span, h, params["gn3"], f"{name}/gn3")
+        if "proj" in params:
+            x = _conv(span, x, params["proj"], f"{name}/proj",
+                      stride=stride)
+            x = _gn(span, x, params["gn_proj"], f"{name}/gn_proj")
+        return jax.nn.relu(x + h), o_max
 
 
 def forward(params, cfg: ResNetDCNConfig, images: Array, *, tap=None,
@@ -193,13 +234,15 @@ def forward(params, cfg: ResNetDCNConfig, images: Array, *, tap=None,
     scale table ({block_name: {"x_scale", "w_scale"}}) consumed when
     ``cfg.quant`` is ``"qat"``/``"int8"``; None = dynamic absmax.
     """
-    x = images.astype(cfg.dtype)
-    x = conv2d(x, params["stem"]["conv"].astype(x.dtype), stride=2,
-               padding=3)
-    x = jax.nn.relu(group_norm(x, params["stem"]["gn"]))
-    x = jax.lax.reduce_window(
-        x, -jnp.inf, jax.lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
-        [(0, 0), (1, 1), (1, 1), (0, 0)])
+    span = _spans(params, images, quant_scales)
+    with span("model/stem", "stem"):
+        x = images.astype(cfg.dtype)
+        x = _conv(span, x, params["stem"]["conv"], "stem/conv", stride=2,
+                  padding=3)
+        x = jax.nn.relu(_gn(span, x, params["stem"]["gn"], "stem/gn"))
+        x = jax.lax.reduce_window(
+            x, -jnp.inf, jax.lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
+            [(0, 0), (1, 1), (1, 1), (0, 0)])
 
     o_maxes: dict[str, Array] = {}
     bi = 0
@@ -211,15 +254,16 @@ def forward(params, cfg: ResNetDCNConfig, images: Array, *, tap=None,
             x, o_max = _apply_block(params[name], x, cfg,
                                     stride=stride, is_dcn=cfg.is_dcn(bi),
                                     name=name, tap=tap,
-                                    quant_scales=scales)
+                                    quant_scales=scales, span=span)
             if o_max is not None:
                 o_maxes[name] = o_max
             bi += 1
 
-    h = conv2d(x, params["head"]["conv"].astype(x.dtype))
-    h = jax.nn.relu(group_norm(h, params["head"]["gn"]))
-    cls = conv2d(h, params["head"]["cls"].astype(x.dtype))
-    box = conv2d(h, params["head"]["box"].astype(x.dtype))
+    with span("model/head", "head"):
+        h = _conv(span, x, params["head"]["conv"], "head/conv")
+        h = jax.nn.relu(_gn(span, h, params["head"]["gn"], "head/gn"))
+        cls = _conv(span, h, params["head"]["cls"], "head/cls")
+        box = _conv(span, h, params["head"]["box"], "head/box")
     return {"cls": cls, "box": box, "features": x}, o_maxes
 
 

@@ -11,7 +11,16 @@ on a fake clock and assert exact durations.
 Disabled tracers are zero-cost: ``span()`` returns one shared no-op
 singleton (no allocation, no clock read, nothing retained), which is
 what lets the dispatcher and serving engine stay instrumented
-unconditionally — the default process-global tracer is disabled.
+unconditionally.
+
+A tracer made with ``profiler=True`` also mirrors each span into the
+JAX profiler: the span enters ``jax.profiler.TraceAnnotation(name,
+**attrs)`` when it starts and leaves it when it ends, so the span lands
+in a profiler trace on the same clock as the device programs it
+launched.  A disabled profiler tracer keeps nothing in memory; with no
+profiler session running its span costs one TraceMe check.  The
+process-global default is such a tracer: nothing is recorded unless a
+profiler session is on (``jax.profiler.start_trace``).
 
 Exports: JSONL (one record per span/event, the CI artifact format) and
 Chrome trace-event JSON (``ph: "X"`` duration + ``ph: "i"`` instant
@@ -25,6 +34,8 @@ import json
 import pathlib
 import time
 
+from jax import profiler as _profiler
+
 __all__ = ["NOOP_SPAN", "Span", "Tracer", "get_tracer", "set_tracer",
            "tracer_scope"]
 
@@ -35,7 +46,7 @@ class Span:
     """
 
     __slots__ = ("_tracer", "name", "span_id", "parent_id", "t0", "t1",
-                 "attrs")
+                 "attrs", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -45,6 +56,7 @@ class Span:
         self.parent_id: int | None = None
         self.t0: float | None = None
         self.t1: float | None = None
+        self._annotation = None
 
     def set_attr(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -54,6 +66,8 @@ class Span:
         tr = self._tracer
         self.parent_id = tr._stack[-1].span_id if tr._stack else None
         tr._stack.append(self)
+        if tr.profiler:
+            self._annotation = _enter_annotation(self.name, self.attrs)
         self.t0 = tr.clock()
         return self
 
@@ -62,6 +76,9 @@ class Span:
             return                       # never started / already ended
         tr = self._tracer
         self.t1 = tr.clock()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if tr._stack and tr._stack[-1] is self:
             tr._stack.pop()
         elif self in tr._stack:          # out-of-order end: drop anyway
@@ -114,13 +131,59 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+_ENDED = object()
+
+
+def _enter_annotation(name: str, attrs: dict):
+    annotation = _profiler.TraceAnnotation(name, **attrs)
+    annotation.__enter__()
+    return annotation
+
+
+class _ProfilerSpan:
+    """Span of a disabled profiler tracer: a profiler annotation and
+    nothing else (no clock read, no record kept)."""
+
+    __slots__ = ("name", "attrs", "_annotation")
+    duration = float("nan")
+
+    def __init__(self, name: str, attrs: dict):
+        self.name = name
+        self.attrs = attrs
+        self._annotation = None
+
+    def set_attr(self, **attrs) -> "_ProfilerSpan":
+        return self
+
+    def start(self) -> "_ProfilerSpan":
+        if self._annotation is None:
+            self._annotation = _enter_annotation(self.name, self.attrs)
+        return self
+
+    def end(self) -> None:
+        annotation, self._annotation = self._annotation, _ENDED
+        if annotation is not None and annotation is not _ENDED:
+            annotation.__exit__(None, None, None)
+
+    def __enter__(self) -> "_ProfilerSpan":
+        return self.start()
+
+    def __exit__(self, *exc) -> bool:
+        self.end()
+        return False
+
+
 class Tracer:
     """See module docstring.  ``spans`` holds finished spans in end
-    order; ``events`` holds instant events in emission order."""
+    order; ``events`` holds instant events in emission order.
+    ``profiler=True`` mirrors every span into the JAX profiler, whether
+    or not the tracer is ``enabled``."""
 
-    def __init__(self, *, clock=time.monotonic, enabled: bool = True):
+    def __init__(self, *, clock=time.monotonic, enabled: bool = True,
+                 profiler: bool = False):
         self.clock = clock
         self.enabled = enabled
+        self.profiler = profiler
         self.spans: list[Span] = []
         self.events: list[dict] = []
         self._stack: list[Span] = []
@@ -130,9 +193,11 @@ class Tracer:
     def span(self, name: str, **attrs):
         """A new child span of the innermost open span (entered lazily:
         the parent is resolved at ``start()``/``__enter__`` time)."""
-        if not self.enabled:
-            return NOOP_SPAN
-        return Span(self, name, attrs)
+        if self.enabled:
+            return Span(self, name, attrs)
+        if self.profiler:
+            return _ProfilerSpan(name, attrs)
+        return NOOP_SPAN
 
     def event(self, name: str, **attrs) -> None:
         """An instant event at the current clock, parented like a span."""
@@ -184,13 +249,14 @@ class Tracer:
 
 
 # ---------------------------------------------------------------------------
-# Process-global default tracer — DISABLED until something opts in
-# (tests via tracer_scope, launchers via --trace).  Instrumented code
-# paths call get_tracer() at use time so a scoped tracer is honored
-# even by objects constructed earlier.
+# Process-global default tracer — keeps nothing in memory until
+# something opts in (tests via tracer_scope, launchers via --trace), and
+# mirrors its spans into the JAX profiler, so a profiler session alone
+# records them.  Instrumented code paths call get_tracer() at use time
+# so a scoped tracer is honored even by objects constructed earlier.
 # ---------------------------------------------------------------------------
 
-_tracer = Tracer(enabled=False)
+_tracer = Tracer(enabled=False, profiler=True)
 
 
 def get_tracer() -> Tracer:

@@ -37,6 +37,14 @@ The model forward runs eagerly (each ``ops.deform_conv*`` call is
 itself jitted per static shape): the dispatch-hook seam and the
 per-request ladder need per-step visibility, which an outer jit would
 collapse to trace time.
+
+Program spans (``repro.obs.trace``): ``serve/step`` and under it
+``serve/batch`` (host pad and device put), ``serve/forward`` (one per
+ladder attempt, with its ``rung``; the model's own ``model/*`` spans
+nest inside), ``serve/fetch`` (waits for the device and copies
+``cls``/``box`` to the host) and ``serve/retire``.  The engine never
+blocks on the device inside the forward: the host waits once, in
+``serve/fetch``.
 """
 from __future__ import annotations
 
@@ -54,8 +62,7 @@ from jax.sharding import Mesh
 from repro.distributed.sharding import use_rules
 from repro.kernels import ops, plan
 from repro.models import resnet_dcn as R
-from repro.obs import (DispatchRecorder, DivergenceTracker, MetricsRegistry,
-                       Tracer)
+from repro.obs import MetricsRegistry, Tracer
 from repro.obs import trace as _trace
 
 from .admission import (AdmissionConfig, AdmissionQueue, DetRequest,
@@ -179,7 +186,6 @@ class DCLServingEngine:
         # time (disabled unless a test/launcher opts in).
         self.metrics = registry if registry is not None else MetricsRegistry()
         self._tracer = tracer
-        self.divergence = DivergenceTracker()
         m = self.metrics
         self._c_requests = m.counter(
             "serve_requests_total", "retired requests by outcome and bucket")
@@ -425,22 +431,14 @@ class DCLServingEngine:
             cfg = dataclasses.replace(cfg, shard_spatial=True)
         mesh_ctx = use_rules(mesh=self._spatial_meshes[bucket]) \
             if spatial else contextlib.nullcontext()
-        # Instrument every bounded dispatch in this forward: the
-        # recorder chains to whatever hook is already installed (the
-        # chaos harness), so injected faults still fire FIRST and abort
-        # before any timing starts.
-        rec = DispatchRecorder(
-            registry=self.metrics, tracer=self._tracer,
-            tracker=self.divergence, next_hook=ops.get_dispatch_hook(),
-            clock=self.clock)
-        with mesh_ctx, ops.dispatch_hook_scope(rec), \
-                ops.degradation_scope(False):
+        with mesh_ctx, ops.degradation_scope(False):
             out, _ = R.forward(self.params, cfg, x, tap=self.tap,
                                quant_scales=self.scale_table)
         return out
 
     def _run_batch(self, bucket: int, reqs: list[DetRequest]) -> None:
-        x = self._batch_array(bucket, reqs)
+        with self._tr.span("serve/batch", bucket=bucket, size=len(reqs)):
+            x = self._batch_array(bucket, reqs)
         rung_idx = LADDER.index(self.scfg.quant)
         if self.scfg.spatial_shards_for(bucket) > 1 \
                 and LADDER[rung_idx] == "int8_chain":
@@ -450,7 +448,13 @@ class DCLServingEngine:
         attempt = 0
         while True:
             try:
-                out = self._forward(LADDER[rung_idx], x, bucket)
+                with self._tr.span("serve/forward", rung=LADDER[rung_idx]):
+                    out = self._forward(LADDER[rung_idx], x, bucket)
+                # The forward only enqueues: a device failure surfaces
+                # when the results are read, and takes the ladder too.
+                with self._tr.span("serve/fetch"):
+                    cls = np.asarray(out["cls"])
+                    box = np.asarray(out["box"])
                 break
             except Exception as e:          # noqa: BLE001 — typed below
                 self._c_retries.inc()
@@ -480,18 +484,17 @@ class DCLServingEngine:
                                  f"{type(e).__name__}: {e}")
                 return
         now = self.clock()
-        cls = np.asarray(out["cls"])
-        box = np.asarray(out["box"])
-        for i, r in enumerate(reqs):
-            r.ladder = LADDER[rung_idx]
-            self._c_ladder.inc(rung=r.ladder)
-            if r.deadline is not None and now > r.deadline:
-                self._retire(r, "deadline_exceeded",
-                             f"completed {now - r.deadline:.3f}s past "
-                             f"deadline (result dropped)")
-                continue
-            r.result = {"cls": cls[i], "box": box[i]}
-            self._retire(r, "ok")
+        with self._tr.span("serve/retire", size=len(reqs)):
+            for i, r in enumerate(reqs):
+                r.ladder = LADDER[rung_idx]
+                self._c_ladder.inc(rung=r.ladder)
+                if r.deadline is not None and now > r.deadline:
+                    self._retire(r, "deadline_exceeded",
+                                 f"completed {now - r.deadline:.3f}s past "
+                                 f"deadline (result dropped)")
+                    continue
+                r.result = {"cls": cls[i], "box": box[i]}
+                self._retire(r, "ok")
 
     def run_until_drained(self, max_steps: int = 10_000
                           ) -> list[DetRequest]:
@@ -537,5 +540,4 @@ class DCLServingEngine:
                 "error": r.error,
             } for r in self.completed],
             "metrics": self.metrics.snapshot(),
-            "divergence": self.divergence.report(),
         }

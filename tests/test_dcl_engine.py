@@ -9,6 +9,7 @@ import pytest
 
 from repro.kernels import ops
 from repro.models import resnet_dcn as R
+from repro.obs import Tracer, tracer_scope
 from repro.quant.calibrate import calibrate_resnet_dcn
 from repro.resilience import KernelDispatchFault
 from repro.serve import (DCLServeConfig, DCLServingEngine, OUTCOMES,
@@ -396,3 +397,39 @@ def test_telemetry_reports_scheduler_config(model):
     tel = eng.telemetry()["engine"]
     assert tel["batch_window"] == 0.5
     assert tel["spatial_shards"] == []
+
+
+# -- program spans --------------------------------------------------------
+
+def test_one_step_span_tree_and_no_device_sync(model, monkeypatch):
+    """One step: serve/step > serve/batch, serve/forward (> one
+    model/block per block, a model/dcl in each DCN block), serve/fetch,
+    serve/retire; and the host never blocks on the device inside it."""
+    cfg = model[0]
+    eng = _engine(model)
+    for i in range(2):
+        eng.submit(_img(40 + i))
+    syncs = []
+    real_sync = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: syncs.append(1) or real_sync(x))
+    tr = Tracer(enabled=True)
+    with tracer_scope(tr):
+        assert eng.step() == 2
+    assert syncs == []
+    spans = sorted(tr.spans, key=lambda s: s.span_id)       # start order
+    kids = {}
+    for s in spans:
+        kids.setdefault(s.parent_id, []).append(s)
+    (step,) = kids[None]
+    assert step.name == "serve/step"
+    assert [s.name for s in kids[step.span_id]] == [
+        "serve/batch", "serve/forward", "serve/fetch", "serve/retire"]
+    forward = kids[step.span_id][1]
+    assert forward.attrs == {"rung": "int8_chain"}
+    blocks = [s for s in kids[forward.span_id] if s.name == "model/block"]
+    assert len(blocks) == cfg.total_blocks
+    dcls = [s for s in spans if s.name == "model/dcl"]
+    assert len(dcls) == sum(cfg.is_dcn(i) for i in range(cfg.total_blocks))
+    assert {d.parent_id for d in dcls} <= {b.span_id for b in blocks}
+    assert "kernel/dispatch" not in {s.name for s in spans}

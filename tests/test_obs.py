@@ -57,6 +57,100 @@ def test_disabled_tracer_is_noop_and_allocates_no_spans():
     assert tr.spans == [] and tr.events == []
 
 
+class _AnnotationLog:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each enter
+    and exit, in order."""
+
+    def __init__(self):
+        self.log = []
+        outer = self
+
+        class Annotation:
+            def __init__(self, name, **attrs):
+                self.name, self.attrs = name, attrs
+
+            def __enter__(self):
+                outer.log.append(("enter", self.name, self.attrs))
+                return self
+
+            def __exit__(self, *exc):
+                outer.log.append(("exit", self.name))
+                return False
+
+        self.cls = Annotation
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    rec = _AnnotationLog()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", rec.cls)
+    return rec
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_profiler_tracer_mirrors_spans_as_annotations(annotations, enabled):
+    tr = Tracer(clock=FakeClock(), enabled=enabled, profiler=True)
+    with tr.span("serve/step", step=3):
+        with tr.span("model/gn", layer="s2b3/gn1"):
+            pass
+        sp = tr.span("serve/fetch").start()
+        sp.end()
+        sp.end()                                   # a second end: no-op
+    assert annotations.log == [
+        ("enter", "serve/step", {"step": 3}),
+        ("enter", "model/gn", {"layer": "s2b3/gn1"}),
+        ("exit", "model/gn"),
+        ("enter", "serve/fetch", {}),
+        ("exit", "serve/fetch"),
+        ("exit", "serve/step")]
+    if enabled:
+        assert [s.name for s in tr.spans] == ["model/gn", "serve/fetch",
+                                              "serve/step"]
+    else:
+        assert tr.spans == [] and tr.events == []  # keeps nothing
+
+
+def test_plain_disabled_tracer_enters_no_annotation(annotations):
+    tr = Tracer(enabled=False)
+    with tr.span("serve/step") as sp:
+        pass
+    assert sp is NOOP_SPAN and annotations.log == []
+
+
+def test_default_tracer_mirrors_to_the_profiler_and_keeps_nothing():
+    from repro.obs import trace as trace_mod
+    default = trace_mod._tracer
+    assert default.profiler and not default.enabled
+
+
+def test_model_spans_only_on_concrete_arrays():
+    """Eager, the forward opens its model/* spans; under jax.jit, and
+    under an eager jax.grad of the params on concrete images, it opens
+    none (they would time tracing, not work)."""
+    from repro.models import resnet_dcn as R
+    cfg = R.ResNetDCNConfig(stage_sizes=(1, 1), widths=(16, 32),
+                            stem_width=8, num_dcn=1, num_classes=2,
+                            img_size=16)
+    params = R.init_params(jax.random.PRNGKey(0), cfg)
+    x = jnp.zeros((1, 16, 16, 3), jnp.float32)
+    eager = Tracer(enabled=True)
+    with tracer_scope(eager):
+        R.forward(params, cfg, x)
+    names = [s.name for s in eager.spans]
+    assert names.count("model/block") == 2
+    assert names.count("model/dcl") == 1
+    assert {"model/stem", "model/head", "model/conv", "model/gn"} \
+        <= set(names)
+    traced = Tracer(enabled=True)
+    with tracer_scope(traced):
+        jax.jit(lambda p, a: R.forward(p, cfg, a)[0]["cls"])(params, x)
+    assert traced.spans == []
+    grad = Tracer(enabled=True)
+    with tracer_scope(grad):
+        jax.grad(lambda p: R.forward(p, cfg, x)[0]["cls"].sum())(params)
+    assert grad.spans == []
+
+
 def test_tracer_scope_restores_previous():
     from repro.obs import get_tracer
     prev = get_tracer()

@@ -109,7 +109,7 @@ def test_serve_chaos_every_request_typed_and_undisturbed_bit_exact(model):
             "fault/dispatch_fault"} <= event_names
     span_names = {s.name for s in tracer.spans}
     assert "serve/step" in span_names
-    assert "kernel/dispatch" in span_names
+    assert "model/dcl" in span_names
 
     # every admitted request retired with a typed outcome; nothing hung
     assert len(eng.completed) == N_REQUESTS
