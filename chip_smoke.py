@@ -211,8 +211,8 @@ def batches_of(reqs, slots):
 
 
 def jitted_forward(cfg, table=None):
-    """``R.forward`` under one jit (the engine's own forward is eager):
-    returns the head outputs and every tapped DCL input/output."""
+    """``R.forward`` under one jit: returns the head outputs and every
+    tapped DCL input/output."""
     import jax
     from repro.models import resnet_dcn as R
 

@@ -18,16 +18,16 @@ fused kernel (``repro.kernels.ops.deform_conv``) — including under
 config trains through the zero-copy Pallas dataflow in both
 directions.  The pure-JAX path remains the parity reference.
 
-Run eagerly (the serving engine), the forward opens a program span
-(``repro.obs.trace``) around each piece of work: ``model/stem``,
-``model/block``, ``model/head``, and inside them ``model/conv`` (an
-XLA conv with its weight cast), ``model/gn`` and ``model/dcl`` (the
-DCL with its int8 dequantize), each with a ``layer`` attribute such as
-``s2b3/conv1``.  A profiler session then puts every device program
-down to the layer that launched it.  When the images, the params or
-the scale table are being traced (``jax.jit``, ``jax.grad``,
-``jax.vmap``) the forward opens no span: it would time tracing, not
-work.
+Run eagerly (calibration, the reference tests), the forward opens a
+program span (``repro.obs.trace``) around each piece of work:
+``model/stem``, ``model/block``, ``model/head``, and inside them
+``model/conv`` (an XLA conv with its weight cast), ``model/gn`` and
+``model/dcl`` (the DCL with its int8 dequantize), each with a
+``layer`` attribute such as ``s2b3/conv1``.  A profiler session then
+puts every device program down to the layer that launched it.  When
+the images, the params or the scale table are being traced
+(``jax.jit``, ``jax.grad``, ``jax.vmap``; the serving engine's
+program) the forward opens no span: it would time tracing, not work.
 """
 from __future__ import annotations
 

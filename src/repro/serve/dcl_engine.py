@@ -33,18 +33,23 @@ Robustness layer (docs/serving.md):
   so two engines in one process keep independent ladders and every
   degraded request reports its own rung.
 
-The model forward runs eagerly (each ``ops.deform_conv*`` call is
-itself jitted per static shape): the dispatch-hook seam and the
-per-request ladder need per-step visibility, which an outer jit would
-collapse to trace time.
+The model forward is one device program per (bucket, rung): the
+engine jits ``R.forward`` on the first batch a bucket serves on a rung
+and launches that program on every later one, so a step enqueues one
+program instead of one per primitive.  The programs belong to the
+engine, and each is traced under ``ops.degradation_scope(False)``: a
+kernel failure, or a raising ``ops.set_dispatch_hook`` (consulted once,
+when the engine first traces the rung), fails the call and takes this
+engine's ladder.  ``serve_forward_traces_total{bucket, rung}`` counts
+the completed traces; in a steady run it stays at 1 per (bucket, rung)
+served.
 
 Program spans (``repro.obs.trace``): ``serve/step`` and under it
-``serve/batch`` (host pad and device put), ``serve/forward`` (one per
-ladder attempt, with its ``rung``; the model's own ``model/*`` spans
-nest inside), ``serve/fetch`` (waits for the device and copies
-``cls``/``box`` to the host) and ``serve/retire``.  The engine never
-blocks on the device inside the forward: the host waits once, in
-``serve/fetch``.
+``serve/batch`` (host pad and device put), ``serve/forward`` (one
+program launch per ladder attempt, with its ``rung``), ``serve/fetch``
+(waits for the device and copies ``cls``/``box`` to the host) and
+``serve/retire``.  The engine never blocks on the device inside the
+forward: the host waits once, in ``serve/fetch``.
 """
 from __future__ import annotations
 
@@ -158,8 +163,10 @@ class DCLServingEngine:
     and ``admit_hook(request)`` are the chaos seams
     (``resilience.ChaosHooks.serve_step_hook`` / ``admit_hook``).
     ``tap(name, x)`` sees every DCL block's input (``name``) and output
-    (``name + "/out"``) of each served batch, as ``R.forward`` taps
-    them — how a caller checks the served layers against a reference."""
+    (``name + "/out"``) of each served batch, in ``R.forward``'s order:
+    the program returns them beside ``cls``/``box`` and the engine hands
+    them on as device arrays after the launch — how a caller checks the
+    served layers against a reference."""
 
     def __init__(self, params, model_cfg: R.ResNetDCNConfig,
                  serve_cfg: DCLServeConfig, *,
@@ -205,11 +212,22 @@ class DCLServingEngine:
         self._h_latency = m.histogram(
             "serve_latency_seconds",
             "submit-to-retire latency per bucket and outcome")
+        self._c_traces = m.counter(
+            "serve_forward_traces_total",
+            "completed traces of the jitted forward per bucket and rung")
 
         if isinstance(scale_table, str):
             from repro.quant.calibrate import load_scale_table
             scale_table = load_scale_table(scale_table)
         self.scale_table = scale_table
+        # The programs take the DCLs' scales as an argument: closed
+        # over, they would be constants, and every calibration table
+        # would compile the program anew.
+        self._scales = None if scale_table is None else {
+            name: {k: jnp.asarray(v, jnp.float32)
+                   for k, v in scale_table[name].items()}
+            for name in bucket_layer_dims(model_cfg, max(serve_cfg.buckets))
+            if name in scale_table}
         if serve_cfg.quant in ("int8_chain", "int8"):
             if model_cfg.offset_bound is None:
                 raise ValueError(
@@ -291,6 +309,9 @@ class DCLServingEngine:
                         spatial_shards=shards) + suffix
                     for name, d in dims.items()}
 
+        # (bucket, rung, model forward) -> (jitted forward, names of its
+        # taps); built on first use, per engine.
+        self._programs: dict[tuple, tuple[Callable, list]] = {}
         self.queue = AdmissionQueue(AdmissionConfig(
             capacity=serve_cfg.queue_capacity,
             policy=serve_cfg.shed_policy))
@@ -419,7 +440,34 @@ class DCLServingEngine:
             images[i, :arr.shape[0], :arr.shape[1], :] = arr
         return jnp.asarray(images)
 
-    def _forward(self, rung: str, x, bucket: int | None = None):
+    def _program(self, bucket: int, rung: str, cfg: R.ResNetDCNConfig):
+        """The jitted forward of one (bucket, rung) and the names of the
+        taps it returns: ``fwd(params, images, scales)``, with the
+        config closed over."""
+        # Keyed on the model's forward as well: one replaced at run time
+        # (a fault a test injects) is traced afresh.
+        key = (bucket, rung, R.forward)
+        if key in self._programs:
+            return self._programs[key]
+        names: list[str] = []
+
+        def forward(params, x, scales):
+            taps: list = []
+            out, _ = R.forward(
+                params, cfg, x, quant_scales=scales,
+                tap=None if self.tap is None
+                else lambda n, a: taps.append((n, a)))
+            names[:] = [n for n, _ in taps]
+            # Counted once the trace is through: one aborted by a
+            # kernel failure or a dispatch hook is the ladder's to count.
+            self._c_traces.inc(bucket=str(bucket), rung=rung)
+            return {"cls": out["cls"], "box": out["box"],
+                    "taps": [a for _, a in taps]}
+
+        self._programs[key] = (jax.jit(forward), names)
+        return self._programs[key]
+
+    def _forward(self, rung: str, x, bucket: int):
         cfg = self._cfgs[rung]
         # Spatial buckets (ISSUE 10): the kernel rungs run height-
         # sharded under the bucket's mesh; the chained rung never gets
@@ -431,9 +479,21 @@ class DCLServingEngine:
             cfg = dataclasses.replace(cfg, shard_spatial=True)
         mesh_ctx = use_rules(mesh=self._spatial_meshes[bucket]) \
             if spatial else contextlib.nullcontext()
+        fwd, names = self._program(bucket, rung, cfg)
+        # The first call traces inside this scope: a kernel failure
+        # raises here instead of baking ops' reference fallback into
+        # the program.
         with mesh_ctx, ops.degradation_scope(False):
-            out, _ = R.forward(self.params, cfg, x, tap=self.tap,
-                               quant_scales=self.scale_table)
+            try:
+                out = fwd(self.params, x, self._scales)
+            except Exception:
+                # JAX retraces a function on every later call once one
+                # of its traces has raised: the next attempt builds the
+                # program from a fresh function.
+                del self._programs[(bucket, rung, R.forward)]
+                raise
+        for name, a in zip(names, out["taps"]):
+            self.tap(name, a)
         return out
 
     def _run_batch(self, bucket: int, reqs: list[DetRequest]) -> None:

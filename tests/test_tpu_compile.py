@@ -1,4 +1,5 @@
-"""Compile-only checks of the bounded DCL forward kernels for TPU v5e.
+"""Compile-only checks of the bounded DCL forward kernels, and of the
+serving engine's whole jitted forward, for TPU v5e.
 
 Interpret mode accepts constructs that the TPU compiler (Mosaic) refuses
 — a row gather on a VMEM value, a ragged int8 band copy, a 4-D block
@@ -13,6 +14,7 @@ The topology is described inside a module fixture, never at import:
 only one process may load the TPU compiler library at a time, and the
 test runner's workers all import this file.
 """
+import dataclasses
 import functools
 
 import jax
@@ -20,7 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import plan
+from repro.kernels import ops, plan
 
 # (H, W, C, stride) of the DCL inputs at 512x512: c3 (3 layers), the
 # strided first c4 layer, c4 (5 layers), the strided first c5 layer,
@@ -93,3 +95,53 @@ def test_forward_kernel_compiles_for_v5e(one_chip, shape, datapath):
     with jax.default_matmul_precision("highest"):
         compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture
+def mosaic_lowering(monkeypatch):
+    """Lower the bounded kernels with Mosaic although the backend is the
+    CPU (``launch.platform.set_platform`` refuses that without a chip);
+    every cache that baked the platform is dropped on both sides."""
+    from repro.launch import platform
+    monkeypatch.setattr(platform, "_platform", "tpu")
+    platform._invalidate_lowering_caches()
+    yield
+    monkeypatch.undo()
+    platform._invalidate_lowering_caches()
+
+
+def test_served_program_compiles_for_v5e(one_chip, mosaic_lowering):
+    """The engine's jitted ``int8_chain`` forward of the published
+    detector (512x512, batch 4, B=2) compiles for v5e as one program
+    that holds all 12 chained DCL kernels."""
+    from repro.configs.resnet50_dcn import CONFIG_BOUNDED
+    from repro.models import resnet_dcn as R
+    from repro.serve import (DCLServeConfig, DCLServingEngine,
+                             bucket_layer_dims)
+
+    res, slots = 512, BATCH
+    cfg = dataclasses.replace(CONFIG_BOUNDED, use_kernel=True)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(functools.partial(R.init_params, cfg=cfg),
+                       jax.random.PRNGKey(0)))
+    # Any scales on the table's layout: the program's shape, not its
+    # numbers, is under test.
+    table = {name: {"x_scale": 0.05, "y_scale": 0.05,
+                    "w_scale": [0.01] * d["m"],
+                    "w_offset_scale": [0.01] * 18}
+             for name, d in bucket_layer_dims(cfg, res).items()}
+    eng = DCLServingEngine(params, cfg,
+                           DCLServeConfig(buckets=(res,), slots=slots),
+                           scale_table=table)
+    fwd, _ = eng._program(res, "int8_chain", eng._cfgs["int8_chain"])
+    x = jax.ShapeDtypeStruct((slots, res, res, 3), jnp.float32,
+                             sharding=one_chip)
+    scales = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        eng._scales)
+    with jax.default_matmul_precision("highest"), \
+            ops.degradation_scope(False):
+        text = fwd.lower(params, x, scales).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        cfg.num_dcn
