@@ -43,6 +43,29 @@ def offset_abs_max(offsets: Array) -> Array:
     return jnp.max(jnp.abs(offsets))
 
 
+def is_modulated(channels: int, taps: int) -> bool:
+    """Whether an offset-conv output of ``channels`` channels belongs to
+    a modulated (DCNv2, Zhu et al. 2019) layer: 3*taps channels, the
+    (dy, dx) offsets then one mask logit per tap, against the 2*taps
+    offsets of a DCNv1 layer.  Any other count raises."""
+    if channels not in (2 * taps, 3 * taps):
+        raise ValueError(
+            f"an offset-conv output has 2*K*K = {2 * taps} channels "
+            f"(DCNv1) or 3*K*K = {3 * taps} (DCNv2: offsets, then mask "
+            f"logits); got {channels}")
+    return channels == 3 * taps
+
+
+def split_offset_channels(o: Array, taps: int) -> tuple[Array, Array | None]:
+    """(offsets, mask) of an offset-conv output: channels ``[0, 2*taps)``
+    are the offsets, and on a modulated layer the mask is the sigmoid of
+    the remaining ``taps`` logits, one factor in [0, 1] per tap (None on
+    a DCNv1 layer).  The Eq. 4 bound applies to the offsets only."""
+    if not is_modulated(o.shape[-1], taps):
+        return o, None
+    return o[..., :2 * taps], jax.nn.sigmoid(o[..., 2 * taps:])
+
+
 def receptive_field(kernel_size: int, o_max: float) -> int:
     """Eq. 4: RF = K_C + 2 * ceil(o_max)."""
     return int(kernel_size + 2 * math.ceil(float(o_max)))
@@ -218,6 +241,10 @@ def dcl_forward(params: dict[str, Array], x: Array, cfg: DCLConfig,
                 *, return_stats: bool = True):
     """Run one DCL: offset conv -> clamp (optional) -> sample -> conv.
 
+    A ``w_offset`` with 3*K*K outputs makes the layer modulated (DCNv2):
+    each sampled tap is multiplied by the sigmoid of its mask logit
+    (``split_offset_channels``).
+
     Returns ``(y, stats)`` where ``stats['o_max']`` is the Eq. 3 statistic
     of the *unclamped* offsets (what the Eq. 5 regularizer penalises) and
     ``stats['offsets']`` the (possibly clamped) offsets actually used.
@@ -232,14 +259,18 @@ def dcl_forward(params: dict[str, Array], x: Array, cfg: DCLConfig,
     if "b_offset" in params:
         o = o + params["b_offset"].astype(cfg.dtype)
     Ho, Wo = o.shape[1], o.shape[2]
+    o, mask = split_offset_channels(o, K * K)
     offsets = o.reshape(N, Ho, Wo, K * K, 2)
 
     o_max = offset_abs_max(offsets)
     if cfg.offset_bound is not None:
         offsets = jnp.clip(offsets, -cfg.offset_bound, cfg.offset_bound)
 
-    # Stage 1b: bilinear sampling (the g of Eq. 2).
+    # Stage 1b: bilinear sampling (the g of Eq. 2), each tap scaled by
+    # its mask on a modulated layer.
     patches = sample_patches(xc, offsets, cfg)  # (N, Ho, Wo, K*K, C)
+    if mask is not None:
+        patches = patches * mask[..., None].astype(patches.dtype)
 
     # Stage 2: dynamic convolution == matmul over (K*K, C) taps (MXU-friendly).
     w = params["w_deform"].astype(cfg.dtype).reshape(K * K, C, cfg.out_channels)

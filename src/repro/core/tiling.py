@@ -634,7 +634,8 @@ def dcl_train_hbm_bytes(shape: LayerShape, t: TileConfig, *,
 def zerocopy_vmem_bytes(shape: LayerShape, t: TileConfig, *,
                         dilation: int = 1, bytes_per_elem: int = 4,
                         aux_bytes_per_elem: int | None = None,
-                        fuse_offsets: bool = False) -> int:
+                        fuse_offsets: bool = False,
+                        offset_channels: int | None = None) -> int:
     """VMEM working set of the zero-copy fused forward kernel — the
     buffers ``kernels.band_pipeline.forward_call`` allocates:
 
@@ -653,8 +654,11 @@ def zerocopy_vmem_bytes(shape: LayerShape, t: TileConfig, *,
     ``aux_bytes_per_elem`` sizes the offsets and the output tile
     separately from the datapath — the int8 kernel keeps both fp32
     (addresses are full precision; the dequant epilogue emits fp32).
+    ``offset_channels`` defaults to 2*K*K; a modulated (DCNv2) layer
+    reads 3*K*K.
     """
     k2 = shape.kernel_size ** 2
+    oc = offset_channels or 2 * k2
     aux_b = aux_bytes_per_elem or bytes_per_elem
     band_h = band_extent(t.t_h, kernel_size=shape.kernel_size,
                          stride=shape.stride, dilation=dilation,
@@ -675,9 +679,9 @@ def zerocopy_vmem_bytes(shape: LayerShape, t: TileConfig, *,
     acc = pixels * t.t_m * 4
     wgt = 2 * k2 * t.t_n * t.t_m * bytes_per_elem
     if fuse_offsets:
-        offs = 2 * k2 * t.t_n * 2 * k2 + pixels * 2 * k2 * 4
+        offs = 2 * k2 * t.t_n * oc + pixels * oc * 4
     else:
-        offs = 2 * pixels * 2 * k2 * aux_b
+        offs = 2 * pixels * oc * aux_b
     out = 2 * pixels * t.t_m * aux_b
     return band + patches + spill + acc + wgt + offs + out
 

@@ -392,7 +392,10 @@ class DCLPlan:
     (int32 -> int8 on the next layer's grid — layer chaining).
     ``fuse_offsets`` computes the offsets in-kernel from the staged band
     (requires ``c_steps == 1``); ``cores`` is the Megacore core axis of
-    the backward grid (forward kernels keep it at 1).
+    the backward grid (forward kernels keep it at 1).  ``modulated``
+    (DCNv2) reads K*K mask logits after the 2*K*K offsets and scales
+    each sampled tap by their sigmoid (``modulate_taps``); a plan
+    without it traces no sigmoid and reads 2*K*K offset columns.
     """
     band: BandSpec
     tile_c: int
@@ -402,6 +405,7 @@ class DCLPlan:
     epilogue: str = "cast"
     fuse_offsets: bool = False
     cores: int = 1
+    modulated: bool = False
 
     def __post_init__(self):
         assert self.epilogue in ("cast", "dequant", "requant"), self.epilogue
@@ -412,6 +416,19 @@ class DCLPlan:
                 f"pipeline stages float32, bfloat16, float16 or int8 "
                 f"bands — cast the input first")
         assert self.acc_dtype in ("float32", "int32"), self.acc_dtype
+
+    @property
+    def offset_channels(self) -> int:
+        """Columns of the offsets (streamed or fused): 2*K*K, plus K*K
+        mask logits on a modulated plan."""
+        return (3 if self.modulated else 2) * self.band.k2
+
+    @property
+    def kernel_name(self) -> str | None:
+        """The ``pallas_call`` name of a modulated forward kernel, so
+        its custom calls stand apart in a trace; None keeps the DCNv1
+        kernels' name as it was."""
+        return "dcnv2_forward_kernel" if self.modulated else None
 
     @property
     def contract(self) -> bool:
@@ -474,7 +491,8 @@ def offset_conv_row(plan: DCLPlan, chunks, t, woff_ref, off_scale_ref,
     (exact int32 accumulation, fp32 dequant).  Requires the whole
     channel extent staged (``c_steps == 1`` — the offsets must be
     complete before the first bilinear sample consumes them).
-    Returns raw fp32 offsets (tile_w, 2*K*K); the Eq. 5 clamp happens in
+    Returns raw fp32 offsets (tile_w, offset_channels: 2*K*K, and K*K
+    mask logits on a modulated plan); the Eq. 5 clamp happens in
     the sampler exactly as for streamed offsets.
     """
     b = plan.band
@@ -490,6 +508,17 @@ def offset_conv_row(plan: DCLPlan, chunks, t, woff_ref, off_scale_ref,
     acc = jnp.dot(lhs, woff_ref[0], precision=jax.lax.Precision.DEFAULT,
                   preferred_element_type=jnp.int32)
     return acc.astype(jnp.float32) * off_scale_ref[...] + off_bias_ref[...]
+
+
+def modulate_taps(taps, off, t, k2: int):
+    """DCNv2's mask for output row ``t``: each sampled tap (tile_w,
+    C-chunk) times the sigmoid of its mask logit, column ``2*K*K + tap``
+    of ``off``.  Applied after the Eq. 4 clamp of the offsets and before
+    the int8 patch round: the mask lies in [0, 1], so a mix of int8
+    values stays in [-127, 127] and the round stays exact."""
+    return [v * jax.nn.sigmoid(
+        off[t, :, pl.ds(2 * k2 + tap, 1)].astype(jnp.float32))
+        for tap, v in enumerate(taps)]
 
 
 # ---------------------------------------------------------------------------
@@ -562,6 +591,8 @@ def _forward_kernel(plan: DCLPlan, has_scale: bool, has_bias: bool, *refs):
 
     def _row(t):
         taps = sample_row_taps(chunks, off, t, b)
+        if plan.modulated:
+            taps = modulate_taps(taps, off, t, k2)
         if requant:
             # The quantized datapath's patch requantization: the convex
             # bilinear mix of int8 values stays in [-127, 127], so this
@@ -624,7 +655,8 @@ def forward_call(plan: DCLPlan, x_pad: Array, offsets: Array | None,
 
     x_pad:   (N, Hp, Wp, C) zero-padded input, left whole in ANY/HBM
              (fp32 or int8 per ``plan.band_dtype``)
-    offsets: (N, Ho, Wo, 2*K*K) raw offsets — ``None`` iff the plan
+    offsets: (N, Ho, Wo, plan.offset_channels) raw offsets (and, on a
+             modulated plan, mask logits) — ``None`` iff the plan
              fuses the offset-conv stage (then ``ho``/``wo`` name the
              padded output extent and ``woff_tiles``/``off_scale``/
              ``off_bias`` carry the quantized offset conv)
@@ -643,6 +675,7 @@ def forward_call(plan: DCLPlan, x_pad: Array, offsets: Array | None,
         (ho, wo, b.tile_h, b.tile_w)
     h_tiles, w_tiles_n = ho // b.tile_h, wo // b.tile_w
     k2 = b.k2
+    oc = plan.offset_channels
     tc = plan.tile_c
     assert c % tc == 0, (c, tc)
     c_steps = c // tc
@@ -654,13 +687,15 @@ def forward_call(plan: DCLPlan, x_pad: Array, offsets: Array | None,
             "staged (c_steps == 1)")
         assert woff_tiles is not None and off_scale is not None \
             and off_bias is not None
+        assert woff_tiles.shape[-1] == oc, (woff_tiles.shape, oc)
     b.check_padded(hp, wp, h_tiles, w_tiles_n)
 
-    # Offsets stream as (N*Ho, Wo, 2*K*K) row blocks: Mosaic cannot
-    # window a 4-D block whose minor dim (2*K*K) is not lane-aligned,
-    # but handles the same bytes as a 3-D block.
+    # Offsets stream as (N*Ho, Wo, offset_channels) row blocks: Mosaic
+    # cannot window a 4-D block whose minor dim (2*K*K or 3*K*K) is not
+    # lane-aligned, but handles the same bytes as a 3-D block.
     if offsets is not None:
-        offsets = offsets.reshape(n * ho, wo, 2 * k2)
+        assert offsets.shape[-1] == oc, (offsets.shape, oc)
+        offsets = offsets.reshape(n * ho, wo, oc)
     grid: tuple[int, ...]
     in_ops: list[Array] = [x_pad]
     in_specs: list = [pl.BlockSpec(memory_space=pl.ANY)]
@@ -676,16 +711,16 @@ def forward_call(plan: DCLPlan, x_pad: Array, offsets: Array | None,
         if not plan.fuse_offsets:
             in_ops.append(offsets)
             in_specs.append(pl.BlockSpec(
-                (b.tile_h, b.tile_w, 2 * k2),
+                (b.tile_h, b.tile_w, oc),
                 lambda i, j, ww, mm, cc: (i * h_tiles + j, ww, 0)))
         else:
             in_ops += [woff_tiles, off_scale, off_bias]
             in_specs += [
-                pl.BlockSpec((1, k2 * tc, 2 * k2),
+                pl.BlockSpec((1, k2 * tc, oc),
                              lambda i, j, ww, mm, cc: (0, 0, 0)),
-                pl.BlockSpec((1, 2 * k2),
+                pl.BlockSpec((1, oc),
                              lambda i, j, ww, mm, cc: (0, 0)),
-                pl.BlockSpec((1, 2 * k2),
+                pl.BlockSpec((1, oc),
                              lambda i, j, ww, mm, cc: (0, 0)),
             ]
         in_ops.append(w_tiles)
@@ -716,7 +751,7 @@ def forward_call(plan: DCLPlan, x_pad: Array, offsets: Array | None,
         scratch.append(pltpu.VMEM((b.tile_h * b.tile_w, tm),
                                   plan.jnp_acc_dtype()))
         if plan.fuse_offsets:
-            scratch.append(pltpu.VMEM((b.tile_h, b.tile_w, 2 * k2),
+            scratch.append(pltpu.VMEM((b.tile_h, b.tile_w, oc),
                                       jnp.float32))
         semantics = ("parallel", "parallel", "parallel", "arbitrary",
                      "arbitrary")
@@ -725,7 +760,7 @@ def forward_call(plan: DCLPlan, x_pad: Array, offsets: Array | None,
         has_scale = has_bias = False
         grid = (n, h_tiles, w_tiles_n, c_steps)
         in_ops.append(offsets)
-        in_specs.append(pl.BlockSpec((b.tile_h, b.tile_w, 2 * k2),
+        in_specs.append(pl.BlockSpec((b.tile_h, b.tile_w, oc),
                                      lambda i, j, ww, cc: (i * h_tiles + j,
                                                            ww, 0)))
         out_specs = pl.BlockSpec((1, b.tile_h, b.tile_w, k2, tc),
@@ -744,4 +779,5 @@ def forward_call(plan: DCLPlan, x_pad: Array, offsets: Array | None,
         scratch_shapes=scratch,
         compiler_params=compiler_params(semantics),
         interpret=interpret,
+        name=plan.kernel_name,
     )(*in_ops)

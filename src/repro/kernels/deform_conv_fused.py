@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.deform_conv import is_modulated
 from .band_pipeline import (BandSpec, DCLPlan, compiler_params,
                             for_each_row, forward_call, sample_row_taps)
 
@@ -50,7 +51,9 @@ def deform_conv_fused_zerocopy(x_pad: Array, offsets: Array,
     """Fused DCL over the whole padded input (no band materialization).
 
     x_pad:   (N, Hp, Wp, C) zero-padded input, left whole in ANY/HBM
-    offsets: (N, Ho, Wo, 2*K*K), Ho = h_tiles*tile_h, Wo = w_tiles*tile_w
+    offsets: (N, Ho, Wo, 2*K*K), Ho = h_tiles*tile_h, Wo = w_tiles*tile_w;
+             (N, Ho, Wo, 3*K*K) with the mask logits of a modulated
+             (DCNv2) layer
     w_tiles: (C//tile_c, K*K*tile_c, M) — deform weights pre-tiled by
              ``plan.tile_weights`` so each C-step reads one contiguous block.
     returns: (N, Ho, Wo, M)
@@ -62,7 +65,8 @@ def deform_conv_fused_zerocopy(x_pad: Array, offsets: Array,
                       dilation=dilation, offset_bound=offset_bound,
                       tile_h=tile_h, tile_w=tile_w),
         tile_c=tile_c or c, tile_m=tile_m or m, epilogue="cast",
-        band_dtype=x_pad.dtype.name)
+        band_dtype=x_pad.dtype.name,
+        modulated=is_modulated(offsets.shape[-1], kernel_size ** 2))
     return forward_call(plan, x_pad, offsets, w_tiles,
                         out_dtype=x_pad.dtype, interpret=interpret)
 

@@ -51,6 +51,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.deform_conv import is_modulated
 from .band_pipeline import BandSpec, DCLPlan, forward_call
 
 Array = jax.Array
@@ -58,13 +59,15 @@ Array = jax.Array
 
 def _int8_plan(*, kernel_size: int, stride: int, dilation: int,
                offset_bound: float, tile_h: int, tile_w: int, tile_c: int,
-               tile_m: int, epilogue: str, fuse_offsets: bool) -> DCLPlan:
+               tile_m: int, epilogue: str, fuse_offsets: bool,
+               offset_channels: int) -> DCLPlan:
     return DCLPlan(
         band=BandSpec(kernel_size=kernel_size, stride=stride,
                       dilation=dilation, offset_bound=offset_bound,
                       tile_h=tile_h, tile_w=tile_w),
         tile_c=tile_c, tile_m=tile_m, band_dtype="int8", acc_dtype="int32",
-        epilogue=epilogue, fuse_offsets=fuse_offsets)
+        epilogue=epilogue, fuse_offsets=fuse_offsets,
+        modulated=is_modulated(offset_channels, kernel_size ** 2))
 
 
 @functools.partial(
@@ -82,7 +85,8 @@ def deform_conv_fused_zerocopy_q(x_pad_q: Array, offsets: Array,
     """int8 fused DCL over the whole padded input (zero-copy dataflow).
 
     x_pad_q:   (N, Hp, Wp, C) int8 zero-padded input, whole in ANY/HBM
-    offsets:   (N, Ho, Wo, 2*K*K) fp32 raw offsets (full precision)
+    offsets:   (N, Ho, Wo, 2*K*K) fp32 raw offsets (full precision);
+               3*K*K channels with a modulated layer's mask logits
     w_tiles_q: (C//tile_c, K*K*tile_c, M) int8 ``plan.tile_weights`` layout
     scale:     (1, M) fp32 combined dequant scale ``s_x * s_w[m]``
     returns:   (N, Ho, Wo, M) fp32 (dequantized by the fused epilogue)
@@ -95,7 +99,7 @@ def deform_conv_fused_zerocopy_q(x_pad_q: Array, offsets: Array,
                       dilation=dilation, offset_bound=offset_bound,
                       tile_h=tile_h, tile_w=tile_w, tile_c=tile_c or c,
                       tile_m=tile_m or m, epilogue="dequant",
-                      fuse_offsets=False)
+                      fuse_offsets=False, offset_channels=offsets.shape[-1])
     return forward_call(plan, x_pad_q, offsets, w_tiles_q, scale=scale,
                         out_dtype=jnp.float32, interpret=interpret)
 
@@ -121,9 +125,11 @@ def deform_conv_fused_zerocopy_chain(x_pad_q: Array, w_tiles_q: Array,
                   once) — the whole C extent is staged per band
                   (``tile_c = C``, required by the fused offset stage)
     w_tiles_q:    (1, K*K*C, M) int8 deform weights
-    woff_tiles_q: (1, K*K*C, 2*K*K) int8 offset-conv weights
-    off_scale:    (1, 2*K*K) fp32 ``s_x * s_woff`` dequant scales
-    off_bias:     (1, 2*K*K) fp32 offset-conv bias
+    woff_tiles_q: (1, K*K*C, 2*K*K) int8 offset-conv weights; 3*K*K
+                  outputs (offsets, then mask logits) for a modulated
+                  (DCNv2) layer
+    off_scale:    (1, 2*K*K or 3*K*K) fp32 ``s_x * s_woff`` dequant scales
+    off_bias:     (1, 2*K*K or 3*K*K) fp32 offset-conv bias
     out_scale:    (1, M) fp32 — ``s_x * s_w[m] / s_y`` (``emit="int8"``,
                   the per-channel requant onto the next layer's grid) or
                   ``s_x * s_w[m]`` (``emit="fp32"``, the chain tail)
@@ -142,7 +148,8 @@ def deform_conv_fused_zerocopy_chain(x_pad_q: Array, w_tiles_q: Array,
                       tile_h=tile_h, tile_w=tile_w, tile_c=c,
                       tile_m=tile_m or m,
                       epilogue="requant" if emit == "int8" else "dequant",
-                      fuse_offsets=True)
+                      fuse_offsets=True,
+                      offset_channels=woff_tiles_q.shape[-1])
     return forward_call(plan, x_pad_q, None, w_tiles_q, scale=out_scale,
                         bias=out_bias, woff_tiles=woff_tiles_q,
                         off_scale=off_scale, off_bias=off_bias, ho=ho, wo=wo,

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.deform_conv import is_modulated
 from .band_pipeline import (  # noqa: F401  (re-export)
     BandSpec, DCLPlan, band_geometry, compiler_params, for_each_row,
     forward_call, sample_row_taps)
@@ -57,7 +58,9 @@ def deform_sample_zerocopy(x_pad: Array, offsets: Array, *, kernel_size: int,
     """Run the zero-copy sampling kernel over the whole padded input.
 
     x_pad:   (N, Hp, Wp, C) zero-padded input, left whole in ANY/HBM
-    offsets: (N, Ho, Wo, 2*K*K), Ho = h_tiles*tile_h, Wo = w_tiles*tile_w
+    offsets: (N, Ho, Wo, 2*K*K), Ho = h_tiles*tile_h, Wo = w_tiles*tile_w;
+             (N, Ho, Wo, 3*K*K) with the mask logits of a modulated
+             (DCNv2) layer, whose patches come out masked
     returns: (N, Ho, Wo, K*K, C) patches
     """
     c = x_pad.shape[-1]
@@ -65,7 +68,8 @@ def deform_sample_zerocopy(x_pad: Array, offsets: Array, *, kernel_size: int,
         band=BandSpec(kernel_size=kernel_size, stride=stride,
                       dilation=dilation, offset_bound=offset_bound,
                       tile_h=tile_h, tile_w=tile_w),
-        tile_c=tile_c or c, tile_m=None, band_dtype=x_pad.dtype.name)
+        tile_c=tile_c or c, tile_m=None, band_dtype=x_pad.dtype.name,
+        modulated=is_modulated(offsets.shape[-1], kernel_size ** 2))
     return forward_call(plan, x_pad, offsets, interpret=interpret)
 
 

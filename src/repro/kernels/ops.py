@@ -82,12 +82,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.deform_conv import DCLConfig, sample_patches
+from repro.core.deform_conv import is_modulated
 from repro.distributed.sharding import batch_mesh_axes
 from repro.distributed import spatial as _spatial
 from . import plan as _plan
 from .deform_sample import deform_sample_banded, deform_sample_zerocopy
 from .matmul import matmul  # re-export  # noqa: F401
+from .ref import deform_sample_ref
 from .plan import (DCSpec as _DCSpec, chain_forward, int8_forward,
                    resolve_tiles, tile_weights, untile_weights)
 
@@ -265,6 +266,19 @@ def check_channel_tiles(c: int, m: int, tile_c: int | None,
             f"chooser)")
 
 
+# Refusals of the modulated (DCNv2) layer, shared with the model layer
+# and the serving engine.
+SPATIAL_DCNV2 = (
+    "a modulated (DCNv2) DCL cannot be spatially sharded "
+    "(shard_spatial=True): the sharded path exchanges the DCNv1 offset "
+    "halo only; serve the image unsharded on one device "
+    "(shard_spatial=False, no spatial_shards entry for its bucket)")
+_BANDED_DCNV2 = (
+    "the legacy banded dataflow samples DCNv1 layers only; run a "
+    "modulated (DCNv2) layer on the zero-copy dataflow "
+    "(dataflow='zero_copy')")
+
+
 def check_batch_split(n: int, *, cores: int = 1,
                       shard_of: int | None = None) -> None:
     """Reject batch splits that don't divide the batch — a clear
@@ -347,25 +361,26 @@ def deform_sample(x: Array, offsets: Array, *, kernel_size: int = 3,
                   interpret: bool | None = None) -> Array:
     """Stage 1: bilinear patch sampling.
 
-    x: (N, H, W, C); offsets: (N, Ho, Wo, 2*K*K) raw offset-conv output.
-    Returns (N, Ho, Wo, K*K, C).
+    x: (N, H, W, C); offsets: (N, Ho, Wo, 2*K*K) raw offset-conv output,
+    or (N, Ho, Wo, 3*K*K) with a modulated (DCNv2) layer's mask logits,
+    whose patches come out masked.  Returns (N, Ho, Wo, K*K, C).
     """
     n, h, w, c = x.shape
     ho, wo = offsets.shape[1], offsets.shape[2]
-    k2 = kernel_size * kernel_size
+    modulated = is_modulated(offsets.shape[-1], kernel_size ** 2)
 
     if offset_bound is None:
         # Unbounded model: irregular-gather baseline (paper's lambda=0).
-        cfg = DCLConfig(in_channels=c, out_channels=1,
-                        kernel_size=kernel_size, stride=stride,
-                        dilation=dilation)
-        return sample_patches(x, offsets.reshape(n, ho, wo, k2, 2), cfg)
+        return deform_sample_ref(x, offsets, kernel_size=kernel_size,
+                                 stride=stride, dilation=dilation)
 
     if interpret is None:
         interpret = default_interpret()
     check_channel_tiles(c, c, tile_c)
 
     if dataflow == "banded":
+        if modulated:
+            raise ValueError(_BANDED_DCNV2)
         th = tile_h or 8
         pad_h = (-ho) % th
         if pad_h:
@@ -506,11 +521,6 @@ def _deform_conv_impl(x: Array, offsets: Array, w: Array, *,
     # NOTE: argument validation lives in the un-jitted ``deform_conv``
     # wrapper (hoisted in PR 6 so validation errors always raise while
     # post-validation failures can degrade to the reference path).
-    n, h, w_, c = x.shape
-    ho, wo = offsets.shape[1], offsets.shape[2]
-    k2 = kernel_size * kernel_size
-    m = w.shape[-1]
-
     if precision == "int8":
         if interpret is None:
             interpret = default_interpret()
@@ -528,10 +538,8 @@ def _deform_conv_impl(x: Array, offsets: Array, w: Array, *,
             x_scale=x_scale, w_scale=w_scale, interpret=interpret)
 
     if offset_bound is None:
-        cfg = DCLConfig(in_channels=c, out_channels=m,
-                        kernel_size=kernel_size, stride=stride,
-                        dilation=dilation)
-        patches = sample_patches(x, offsets.reshape(n, ho, wo, k2, 2), cfg)
+        patches = deform_sample_ref(x, offsets, kernel_size=kernel_size,
+                                    stride=stride, dilation=dilation)
         y = jnp.einsum("nhwkc,kcm->nhwm", patches, w,
                        preferred_element_type=jnp.float32)
         return y.astype(x.dtype)
@@ -592,7 +600,12 @@ def deform_conv(x: Array, offsets: Array, w: Array, *, kernel_size: int = 3,
     """Fused DCL stage 1+2: y = g(x, o) * w_deform  (Eq. 2).
 
     x: (N, H, W, C); offsets: (N, Ho, Wo, 2*K*K); w: (K*K, C, M).
-    Returns (N, Ho, Wo, M).  Unspecified tile sizes are resolved by the
+    Returns (N, Ho, Wo, M).  Offsets with 3*K*K channels make the layer
+    modulated (DCNv2): the last K*K are mask logits, and every datapath
+    scales each sampled tap by their sigmoid.  A modulated layer runs
+    forward only on the kernels (its gradient raises: train it with
+    ``use_kernel=False``), and refuses the banded dataflow and
+    ``shard_spatial``.  Unspecified tile sizes are resolved by the
     Sec. 3.2 chooser against the combined fwd+bwd zero-copy traffic
     model.  The bounded path is differentiable end-to-end: ``jax.grad``
     routes through the fused backward kernel of ``deform_conv_bwd.py``
@@ -640,6 +653,11 @@ def deform_conv(x: Array, offsets: Array, w: Array, *, kernel_size: int = 3,
     """
     # -- validation (always raises; never degraded) -------------------
     c, m = x.shape[-1], w.shape[-1]
+    if is_modulated(offsets.shape[-1], kernel_size ** 2):
+        if dataflow == "banded":
+            raise ValueError(_BANDED_DCNV2)
+        if shard_spatial:
+            raise ValueError(SPATIAL_DCNV2)
     if precision not in ("fp32", "int8"):
         raise ValueError(
             f"unknown precision {precision!r}; expected 'fp32' or 'int8'")
@@ -802,7 +820,8 @@ def deform_conv_chain(x: Array, w: Array, w_offset: Array,
     x: (N, H, W, C) — int8 values on the ``x_scale`` grid (the previous
     chained layer's emission) or fp32 (the chain head, quantized here
     with ``x_scale``).  w: (K*K, C, M) fp32 deform weights; w_offset:
-    (K*K, C, 2*K*K) fp32 offset-conv weights; b_offset/b_deform the
+    (K*K, C, 2*K*K) fp32 offset-conv weights, or (K*K, C, 3*K*K) with
+    the mask logits of a modulated (DCNv2) layer; b_offset/b_deform the
     biases (the deform bias is folded into the requant epilogue —
     int8 emission must quantize ``y + b``, not ``y``).
 

@@ -493,6 +493,11 @@ def bounded_backward(spec: DCSpec, x: Array, offsets: Array, w: Array,
     per-shard body of the ``shard_map`` VJP."""
     n, h, w_, c = x.shape
     ho, wo = offsets.shape[1], offsets.shape[2]
+    if offsets.shape[-1] != 2 * spec.kernel_size ** 2:
+        raise NotImplementedError(
+            "the fused backward kernel differentiates DCNv1 layers only "
+            "(2*K*K offset channels); train a modulated (DCNv2) config "
+            "through the pure-JAX path, use_kernel=False")
     th, tw, tc, _ = spec_tiles(spec, x, offsets, w)
     off_dtype = offsets.dtype
     dwf = spec.dw_flush_every_step
@@ -590,9 +595,10 @@ def chain_forward(x: Array, w: Array, w_offset: Array, b_offset: Array,
     previous chained layer's emission) or a fp32 plane quantized here
     (the chain head).  The offset conv is fused into the kernel
     (quantized weights ``w_offset``/``w_offset_scale``, fp32 dequant +
-    ``b_offset``), and the output is emitted int8 on the ``y_scale``
-    grid with the per-channel requant ``s_x * s_w[m] / s_y`` and the
-    deform bias folded as ``b[m] / s_y`` (``emit="fp32"`` is the chain
+    ``b_offset``; 3*K*K outputs make the layer modulated, DCNv2), and
+    the output is emitted int8 on the ``y_scale`` grid with the
+    per-channel requant ``s_x * s_w[m] / s_y`` and the deform bias
+    folded as ``b[m] / s_y`` (``emit="fp32"`` is the chain
     tail: plain dequant + bias).  The chained layer's activations touch
     HBM only as int8 — no fp32 round-trip and no offsets in HBM at all.
     """
@@ -600,7 +606,7 @@ def chain_forward(x: Array, w: Array, w_offset: Array, b_offset: Array,
 
     n, h, w_in, c = x.shape
     m = w.shape[-1]
-    k2 = kernel_size * kernel_size
+    n_off = w_offset.shape[-1]          # 2*K*K, or 3*K*K modulated
     ho, wo = out_hw(h, w_in, kernel_size=kernel_size, stride=stride,
                     dilation=dilation)
     if tile_c is not None and tile_c != c:
@@ -629,7 +635,8 @@ def chain_forward(x: Array, w: Array, w_offset: Array, b_offset: Array,
                        kernel_size=kernel_size, stride=stride,
                        offset_bound=offset_bound),
             TileConfig(th_, tw_, c, tm), dilation=dilation,
-            bytes_per_elem=1, aux_bytes_per_elem=4, fuse_offsets=True)
+            bytes_per_elem=1, aux_bytes_per_elem=4, fuse_offsets=True,
+            offset_channels=n_off)
     while _chain_vmem(th, tw) > VMEM_LIMIT_BYTES and (th > 1 or tw > 1):
         if tw > 1:
             tw = max(1, tw // 2)
@@ -640,7 +647,7 @@ def chain_forward(x: Array, w: Array, w_offset: Array, b_offset: Array,
     sw = compute_scale(w, axis=-1) if w_scale is None \
         else jnp.asarray(w_scale, jnp.float32).reshape(1, 1, m)
     swo = compute_scale(w_offset, axis=-1) if w_offset_scale is None \
-        else jnp.asarray(w_offset_scale, jnp.float32).reshape(1, 1, 2 * k2)
+        else jnp.asarray(w_offset_scale, jnp.float32).reshape(1, 1, n_off)
     xq = x if x.dtype == jnp.int8 else quantize_values(x, sx)
     wq = quantize_values(w, sw)
     woq = quantize_values(w_offset, swo)
@@ -652,8 +659,8 @@ def chain_forward(x: Array, w: Array, w_offset: Array, b_offset: Array,
         ho=ho + pad_h, wo=wo + pad_w)
     w_tiled = tile_weights(wq, c)
     wo_tiled = tile_weights(woq, c)
-    off_scale = (sx * swo).reshape(1, 2 * k2).astype(jnp.float32)
-    off_bias = jnp.asarray(b_offset, jnp.float32).reshape(1, 2 * k2)
+    off_scale = (sx * swo).reshape(1, n_off).astype(jnp.float32)
+    off_bias = jnp.asarray(b_offset, jnp.float32).reshape(1, n_off)
     bias = jnp.zeros((m,), jnp.float32) if b_deform is None \
         else jnp.asarray(b_deform, jnp.float32)
     if emit == "int8":

@@ -10,7 +10,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.deform_conv import DCLConfig, sample_patches
+from repro.core.deform_conv import (DCLConfig, sample_patches,
+                                    split_offset_channels)
 
 Array = jax.Array
 
@@ -31,20 +32,27 @@ def deform_sample_ref(x: Array, offsets: Array, *, kernel_size: int = 3,
     """Oracle for the stage-1 sampling kernel.
 
     x:       (N, H, W, C)
-    offsets: (N, Ho, Wo, 2*K*K) raw offset-conv output, pairs (dy, dx)
+    offsets: (N, Ho, Wo, 2*K*K) raw offset-conv output, pairs (dy, dx);
+             (N, Ho, Wo, 3*K*K) for a modulated (DCNv2) layer, whose
+             last K*K channels are the mask logits
     returns: (N, Ho, Wo, K*K, C) bilinearly interpolated patches.
 
     Semantics match the kernel: offsets are clamped to ``offset_bound``
     (the trained Eq. 5 bound) before sampling; samples outside the image
-    contribute zero.
+    contribute zero; a modulated layer's taps are then multiplied by the
+    sigmoid of their mask logits.
     """
     n, _, _, c = x.shape
     k2 = kernel_size * kernel_size
     ho, wo = offsets.shape[1], offsets.shape[2]
+    offsets, mask = split_offset_channels(offsets, k2)
     off = offsets.reshape(n, ho, wo, k2, 2)
     if offset_bound is not None:
         off = jnp.clip(off, -offset_bound, offset_bound)
-    return sample_patches(x, off, _cfg(c, kernel_size, stride, dilation))
+    patches = sample_patches(x, off, _cfg(c, kernel_size, stride, dilation))
+    if mask is None:
+        return patches
+    return patches * mask[..., None].astype(patches.dtype)
 
 
 def flash_attention_ref(q: Array, k: Array, v: Array, *, causal: bool = True,

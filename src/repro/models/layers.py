@@ -492,13 +492,18 @@ def attn_cache_def(cfg: AttnConfig, batch: int, max_len: int,
 # Deformable convolution layer (shared conv-backbone primitive)
 # ---------------------------------------------------------------------------
 
-def dcl_def(cin: int, cout: int, k: int = 3) -> dict[str, ParamDef]:
+def dcl_def(cin: int, cout: int, k: int = 3, *,
+            modulated: bool = False) -> dict[str, ParamDef]:
     """Parameter tree of one DCL: offset conv (zero-init, the paper's
-    'offsets start at the regular grid') + deform conv weights."""
+    'offsets start at the regular grid') + deform conv weights.  A
+    modulated (DCNv2) layer's offset conv has 3*K*K outputs: the 2*K*K
+    offsets, then K*K mask logits (zero-init: every mask starts at
+    sigmoid(0) = 0.5, as in DCNv2)."""
+    n_off = (3 if modulated else 2) * k * k
     return {
-        "w_offset": ParamDef((k, k, cin, 2 * k * k), (None, None, None, None),
+        "w_offset": ParamDef((k, k, cin, n_off), (None, None, None, None),
                              init="zeros"),
-        "b_offset": ParamDef((2 * k * k,), (None,), init="zeros"),
+        "b_offset": ParamDef((n_off,), (None,), init="zeros"),
         "w_deform": ParamDef((k, k, cin, cout),
                              (None, None, None, "conv_out")),
         "b_deform": ParamDef((cout,), (None,), init="zeros"),
@@ -547,6 +552,12 @@ def dcl_apply(params: Mapping[str, Array], x: Array, *,
     ``"int8_chain"``: the chained plan stages full-height fused-offset
     bands, so spatial serve buckets ladder from ``"int8"``.
 
+    A ``w_offset`` with 3*K*K outputs makes the layer modulated
+    (DCNv2, Zhu et al. 2019): the last K*K are mask logits, and every
+    path below scales each sampled tap by their sigmoid.  Modulated
+    layers refuse ``shard_spatial`` and the kernel path's gradient
+    (train them with ``use_kernel=False``).
+
     ``quant`` selects the int8 datapath modes of ``repro.quant``:
 
     * ``"qat"`` — fake-quantize the deform-conv operands (activation
@@ -575,11 +586,18 @@ def dcl_apply(params: Mapping[str, Array], x: Array, *,
       through the production ``Trainer`` unchanged.
     """
     from repro.core.deform_conv import (DCLConfig, conv2d, dcl_forward,
-                                        offset_abs_max)
+                                        is_modulated, offset_abs_max)
     if quant not in ("none", "qat", "int8", "int8_chain"):
         raise ValueError(
             f"unknown quant mode {quant!r}; expected 'none', 'qat', "
             f"'int8' or 'int8_chain'")
+    # The Eq. 3 statistic reads the offsets, not a modulated layer's
+    # mask logits after them.
+    n_off = 2 * kernel_size * kernel_size
+    if shard_spatial and is_modulated(params["w_offset"].shape[-1],
+                                      kernel_size * kernel_size):
+        from repro.kernels.ops import SPATIAL_DCNV2
+        raise ValueError(SPATIAL_DCNV2)
     if quant == "int8_chain":
         # Fail loudly on configuration the chained datapath cannot
         # honor (mirroring the int8 branch's dataflow passthrough
@@ -635,7 +653,7 @@ def dcl_apply(params: Mapping[str, Array], x: Array, *,
         offsets = conv2d(xc, params["w_offset"].astype(xc.dtype),
                          stride=stride, dilation=dilation, padding=cfg.pad)
         offsets = offsets + params["b_offset"].astype(xc.dtype)
-        o_max = offset_abs_max(offsets)
+        o_max = offset_abs_max(offsets[..., :n_off])
         w = params["w_deform"].astype(xc.dtype).reshape(k * k, cin, cout)
         x_scale = quant_scales.get("x_scale") if quant_scales else None
         w_scale = quant_scales.get("w_scale") if quant_scales else None
@@ -682,7 +700,7 @@ def dcl_apply(params: Mapping[str, Array], x: Array, *,
         offsets = conv2d(x, params["w_offset"].astype(x.dtype),
                          stride=stride, dilation=dilation, padding=cfg.pad)
         offsets = offsets + params["b_offset"].astype(x.dtype)
-        o_max = offset_abs_max(offsets)
+        o_max = offset_abs_max(offsets[..., :n_off])
         w = params["w_deform"].astype(x.dtype).reshape(k * k, cin, cout)
         y = ops.deform_conv(x, offsets, w, kernel_size=k, stride=stride,
                             dilation=dilation, offset_bound=offset_bound,
@@ -732,7 +750,7 @@ def _dcl_chain_layer(params: Mapping[str, Array], x, *, kernel_size: int,
     k = kernel_size
     w = params["w_deform"].astype(jnp.float32).reshape(k * k, cin, cout)
     w_off = params["w_offset"].astype(jnp.float32) \
-        .reshape(k * k, cin, 2 * k * k)
+        .reshape(k * k, cin, params["w_offset"].shape[-1])
 
     if use_kernel:
         from repro.kernels import ops
@@ -778,7 +796,7 @@ def _dcl_chain_layer(params: Mapping[str, Array], x, *, kernel_size: int,
         offset_bound=offset_bound, x_scale=x_scale, w_scale=w_scale,
         w_offset_scale=wo_scale, y_scale=y_scale)
     from repro.core.deform_conv import offset_abs_max
-    return y, offset_abs_max(offsets)
+    return y, offset_abs_max(offsets[..., :2 * k * k])
 
 
 def check_chain_compat(scales_seq: Sequence[Mapping[str, Any]],

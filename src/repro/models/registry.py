@@ -58,7 +58,7 @@ _REGISTRY: dict[str, ArchSpec] = {}
 ARCH_MODULES = [
     "command_r_35b", "tinyllama_1_1b", "glm4_9b", "deepseek_7b",
     "recurrentgemma_9b", "musicgen_medium", "pixtral_12b", "rwkv6_3b",
-    "dbrx_132b", "grok_1_314b", "resnet50_dcn",
+    "dbrx_132b", "grok_1_314b", "resnet50_dcn", "resnet101_dcnv2",
 ]
 
 

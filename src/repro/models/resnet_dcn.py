@@ -23,7 +23,9 @@ program span (``repro.obs.trace``) around each piece of work:
 ``model/stem``, ``model/block``, ``model/head``, and inside them
 ``model/conv`` (an XLA conv with its weight cast), ``model/gn`` and
 ``model/dcl`` (the DCL with its int8 dequantize), each with a
-``layer`` attribute such as ``s2b3/conv1``.  A profiler session then
+``layer`` attribute such as ``s2b3/conv1``; ``model/dcl`` adds
+``modulated`` (a DCNv2 layer) and ``offset_channels`` (18, or 27 with
+the mask logits).  A profiler session then
 puts every device program down to the layer that launched it.  When
 the images, the params or the scale table are being traced
 (``jax.jit``, ``jax.grad``, ``jax.vmap``; the serving engine's
@@ -37,7 +39,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.core.deform_conv import conv2d
+from repro.core.deform_conv import conv2d, is_modulated
 from repro.obs.trace import NOOP_SPAN, get_tracer
 from .layers import ParamDef, dcl_apply, dcl_def
 
@@ -75,6 +77,10 @@ class ResNetDCNConfig:
     # exchange per DCL (distributed.spatial).  None/False = off,
     # True = require (ValueError when no mesh / ragged heights).
     shard_spatial: bool | None = None
+    # Modulated DCLs (DCNv2, Zhu et al. 2019): each DCL's offset conv
+    # also emits K*K mask logits and every sampled tap is scaled by
+    # their sigmoid.  The Eq. 4 bound applies to the offsets only.
+    modulated: bool = False
 
     @property
     def total_blocks(self) -> int:
@@ -108,18 +114,20 @@ def group_norm(x: Array, params, *, groups: int = GN_GROUPS,
     return (y * params["scale"] + params["bias"]).astype(x.dtype)
 
 
-def _no_span(name: str, layer: str):
+def _no_span(name: str, layer: str, **attrs):
     return NOOP_SPAN
 
 
 def _spans(*inputs):
-    """``span(name, layer)`` for one forward: a program span around
-    eager work, or none at all if any of ``inputs`` is being traced."""
+    """``span(name, layer, **attrs)`` for one forward: a program span
+    around eager work, or none at all if any of ``inputs`` is being
+    traced."""
     if any(isinstance(a, jax.core.Tracer)
            for a in jax.tree_util.tree_leaves(inputs)):
         return _no_span
     tracer = get_tracer()
-    return lambda name, layer: tracer.span(name, layer=layer)
+    return lambda name, layer, **attrs: tracer.span(name, layer=layer,
+                                                    **attrs)
 
 
 def _conv(span, x, w, layer: str, **kw):
@@ -132,10 +140,6 @@ def _gn(span, x, params, layer: str):
         return group_norm(x, params)
 
 
-def _dcl_def(cin, cout, k=3):
-    return dcl_def(cin, cout, k)
-
-
 def _block_def(cfg: ResNetDCNConfig, cin, width, block_index,
                *, downsample: bool):
     mid = width // 4
@@ -145,7 +149,7 @@ def _block_def(cfg: ResNetDCNConfig, cin, width, block_index,
         "conv3": _conv_def(1, 1, mid, width), "gn3": _gn_def(width),
     }
     if cfg.is_dcn(block_index):
-        d["dcl"] = _dcl_def(mid, mid)
+        d["dcl"] = dcl_def(mid, mid, modulated=cfg.modulated)
     else:
         d["conv2"] = _conv_def(3, 3, mid, mid)
     if downsample or cin != width:
@@ -201,7 +205,10 @@ def _apply_block(params, x: Array, cfg: ResNetDCNConfig, *, stride: int,
         if is_dcn:
             if tap is not None:
                 tap(name, h)
-            with span("model/dcl", f"{name}/dcl"):
+            kh, kw, _, n_off = params["dcl"]["w_offset"].shape
+            with span("model/dcl", f"{name}/dcl",
+                      modulated=is_modulated(n_off, kh * kw),
+                      offset_channels=n_off):
                 h, o_max = _apply_dcl(params["dcl"], h, cfg, stride=stride,
                                       quant_scales=quant_scales)
                 if hasattr(h, "dequantize"):

@@ -106,7 +106,11 @@ def fake_quant_dcl_chain_reference(x: Array, w: Array, w_offset: Array,
       (the per-channel requant epilogue; ``y_scale=None`` is the fp32
       chain tail).
 
-    Returns ``(y, offsets)`` — offsets feed the Eq. 5 ``o_max``
+    A modulated (DCNv2) layer's ``w_offset`` has 3*K*K outputs and its
+    taps are masked before the patch round (``deform_sample_ref``).
+
+    Returns ``(y, offsets)`` — offsets (and a modulated layer's mask
+    logits after them) feed the Eq. 5 ``o_max``
     statistic, which the fused kernel never materializes.  Values agree
     with the kernel to <= 1 LSB of the emission grid (fp32 product
     rounding in the offset conv vs the kernel's exact int32
@@ -116,7 +120,6 @@ def fake_quant_dcl_chain_reference(x: Array, w: Array, w_offset: Array,
     from repro.kernels.ref import deform_sample_ref
 
     k = kernel_size
-    k2 = k * k
     cin = x.shape[-1]
     cout = w.shape[-1]
     if x_scale is None:
@@ -130,12 +133,13 @@ def fake_quant_dcl_chain_reference(x: Array, w: Array, w_offset: Array,
     else:
         wq = fake_quant(w, jnp.asarray(w_scale, jnp.float32)
                         .reshape(1, 1, cout))
+    n_off = w_offset.shape[-1]          # 2*K*K, or 3*K*K modulated
     if w_offset_scale is None:
         woq = fake_quant_absmax(w_offset, axis=-1)
     else:
         woq = fake_quant(w_offset, jnp.asarray(w_offset_scale, jnp.float32)
-                         .reshape(1, 1, 2 * k2))
-    offsets = conv2d(xq, woq.reshape(k, k, cin, 2 * k2), stride=stride,
+                         .reshape(1, 1, n_off))
+    offsets = conv2d(xq, woq.reshape(k, k, cin, n_off), stride=stride,
                      dilation=dilation, padding=dilation * (k // 2))
     offsets = offsets + jnp.asarray(b_offset, jnp.float32)
     patches = deform_sample_ref(

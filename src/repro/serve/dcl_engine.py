@@ -26,7 +26,9 @@ Robustness layer (docs/serving.md):
   ``max_retries`` per rung.
 * per-request degradation ladder — persistent failures drop the batch
   one rung (int8_chain -> int8 -> fp32 kernel -> XLA reference) and
-  replay.  The engine runs each batch under
+  replay.  Every rung computes a modulated (DCNv2) model's masks; a
+  modulated engine refuses spatial buckets at construction.  The
+  engine runs each batch under
   ``ops.degradation_scope(False)`` so kernel failures surface HERE and
   are recorded in each affected request's telemetry (``ladder``,
   ``degraded``) — not in ``ops``'s process-global warn-once fallback,
@@ -261,6 +263,9 @@ class DCLServingEngine:
         # fails at construction, not on the first sharded request.
         self._spatial_meshes: dict[int, Mesh] = {}
         for b, s in serve_cfg.spatial_shards:
+            if s > 1 and model_cfg.modulated:
+                raise ValueError(f"spatial_shards={s} for bucket {b}: "
+                                 f"{ops.SPATIAL_DCNV2}")
             if s > jax.device_count():
                 raise ValueError(
                     f"spatial_shards={s} for bucket {b} exceeds the "

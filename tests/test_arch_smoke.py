@@ -95,11 +95,12 @@ def test_long_context_archs_decode(name):
 
 
 def test_registry_cells_accounting():
-    """40 LM cells = 32 runnable + 8 recorded skips; + 4 CNN cells."""
+    """40 LM cells = 32 runnable + 8 recorded skips; + 6 CNN cells (two
+    shapes each of the three ResNet-DCN archs)."""
     cells = reg.runnable_cells()
     skips = reg.skipped_cells()
-    lm = [c for c in cells if not c[0].startswith("resnet50")]
-    cnn = [c for c in cells if c[0].startswith("resnet50")]
+    lm = [c for c in cells if not c[0].startswith("resnet")]
+    cnn = [c for c in cells if c[0].startswith("resnet")]
     assert len(lm) + len(skips) == 40
-    assert len(cnn) == 4
+    assert len(cnn) == 6
     assert all(s[1] == "long_500k" for s in skips)

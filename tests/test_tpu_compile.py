@@ -53,13 +53,14 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
-def _program(datapath, h, w, c, s, dev):
+def _program(datapath, h, w, c, s, dev, n_off=18):
     """The jitted per-layer program of one datapath — padding,
     quantization, weight blocking and the kernel, exactly as
     ``ops.deform_conv`` / ``ops.deform_conv_chain`` run it — and its
-    argument shapes."""
-    def arg(shape, dtype=jnp.float32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+    argument shapes.  ``n_off=27`` makes the chain layer modulated
+    (DCNv2)."""
+    def arg(shape, dtype=jnp.float32, sharding=dev):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     ho, wo = (h - 1) // s + 1, (w - 1) // s + 1
     geo = dict(kernel_size=3, stride=s, dilation=1, offset_bound=BOUND)
@@ -81,8 +82,8 @@ def _program(datapath, h, w, c, s, dev):
             x, wt, w_off, b_off, b, x_scale=sx, w_scale=sw,
             w_offset_scale=swo, y_scale=sy, emit="int8", interpret=False,
             **geo, **tiles)
-    return fn, (x, wt, arg((9, c, 18)), arg((18,)), arg((c,)), arg(()),
-                arg((c,)), arg((18,)), arg(()))
+    return fn, (x, wt, arg((9, c, n_off)), arg((n_off,)), arg((c,)),
+                arg(()), arg((c,)), arg((n_off,)), arg(()))
 
 
 @pytest.mark.parametrize("datapath", DATAPATHS)
@@ -95,6 +96,47 @@ def test_forward_kernel_compiles_for_v5e(one_chip, shape, datapath):
     with jax.default_matmul_precision("highest"):
         compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape", SHAPES,
+                         ids=lambda s: "{}x{}x{}_s{}".format(*s))
+def test_modulated_chain_kernel_compiles_for_v5e(one_chip, shape):
+    """The DCNv2 chain kernel (27 fused offset-conv outputs, the per-tap
+    sigmoid mask) at the c3, c4 and c5 shapes of ResNet-101 at 512x512,
+    under its own kernel name."""
+    fn, args = _program("chain", *shape, one_chip, n_off=27)
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "%dcnv2_forward_kernel" in text
+
+
+def _kernel_eqns(jaxpr):
+    """Every ``pallas_call`` equation of ``jaxpr``, nested ones too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _kernel_eqns(sub)
+
+
+@pytest.mark.parametrize("n_off", (18, 27))
+def test_modulation_is_traced_only_by_modulated_plans(n_off):
+    """A DCNv1 plan traces the kernel it always did: no sigmoid in its
+    body, 2*K*K offset columns read and its usual name; a modulated plan
+    reads 3*K*K and applies the sigmoid (traced here, not compiled)."""
+    fn, args = _program("chain", 32, 32, 256, 1, None, n_off=n_off)
+    eqns = list(_kernel_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+    assert len(eqns) == 1
+    kernel = eqns[0].params["jaxpr"]
+    assert ("logistic" in str(kernel)) == (n_off == 27)
+    blocks = [bm.block_shape for bm in
+              eqns[0].params["grid_mapping"].block_mappings]
+    # The fused offset-conv weights, scales and bias: n_off columns.
+    assert [getattr(b[-1], "block_size", b[-1]) for b in blocks[1:4]] \
+        == [n_off] * 3
+    name = eqns[0].params["name"]
+    assert (name == "dcnv2_forward_kernel") == (n_off == 27)
 
 
 @pytest.fixture
